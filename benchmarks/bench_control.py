@@ -27,17 +27,12 @@ Two grids:
 
 * **Batched axis** (always) — the acceptance demo for the vmapped rollout
   core: one 3-day ICO trace is run through the 2-seed per-chunk Python
-  loop twice — once on the **pre-PR core** (subprocess with
-  ``REPRO_GAMMA_REJECTION=1``: rejection-sampler gamma, what the protocol
-  actually cost before this change) and once on the current loop (shares
-  the new Erlang sampler) — then its placement/action plans, one without
-  mitigation and one with a reactive ControlLoop, are replayed over
-  >= 20 sim seeds in ONE ``state.batched_rollout`` call each.  Reported:
-  all three wall-clocks (the bar: 20+ vmapped seeds cheaper than the
-  pre-PR 2-seed loop), per-seed cost of each path, p99 mean +/- std per
-  mode across seeds, the per-seed mitigation win/loss record, and a
-  parity check (the replay entry whose seed equals the reference run's
-  must reproduce its p99).
+  loop, then its placement/action plans, one without mitigation and one
+  with a reactive ControlLoop, are replayed over >= 20 sim seeds in ONE
+  ``state.batched_rollout`` call each.  Reported: both wall-clocks,
+  per-seed cost of each path, p99 mean +/- std per mode across seeds, the
+  per-seed mitigation win/loss record, and a parity check (the replay
+  entry whose seed equals the reference run's must reproduce its p99).
 
 Cost-model calibration (total predicted vs realized reduction, per-kind
 corrections) is carried exactly as before.
@@ -205,53 +200,13 @@ def _profile_grid(predictor, seeds, out, json_doc):
     }
 
 
-_LEGACY_BASELINE_SCRIPT = """
-import json, sys, time
-import numpy as np
-from repro.cluster.experiment import bursty_trace, run_experiment
-from repro.core import ICOScheduler, InterferenceQuantifier
-pods, gaps = bursty_trace(seed=0, **{trace!r})
-walls, p99 = [], []
-for sim_seed in (11, 12):
-    sched = ICOScheduler(InterferenceQuantifier(
-        lambda x: np.asarray(x)[:, 0] * 0.1))
-    t0 = time.time()
-    r = run_experiment(sched, pods, gaps, num_nodes=12, seed=sim_seed,
-                       control_window={window}, fast=False)
-    walls.append(time.time() - t0)
-    p99.append(r.p99_rt)
-print(json.dumps({{"wall_s": sum(walls), "p99": p99}}))
-"""
-
-
-def _legacy_baseline_wall() -> dict:
-    """Time the pre-PR core — per-chunk Python loop + rejection-sampler
-    gamma — on the 3-day trace, 2 sim seeds.  Runs in a subprocess with
-    REPRO_GAMMA_REJECTION=1 because the sampler choice is baked into the
-    jitted graphs at import time."""
-    import os
-    import subprocess
-
-    script = _LEGACY_BASELINE_SCRIPT.format(trace=PROACTIVE_TRACE,
-                                            window=CONTROL_WINDOW)
-    proc = subprocess.run(
-        [sys.executable, "-c", script],
-        env={**os.environ, "REPRO_GAMMA_REJECTION": "1"},
-        capture_output=True, text=True, check=True,
-    )
-    return json.loads(proc.stdout.strip().splitlines()[-1])
-
-
 def _batched_axis(out, json_doc, sim_seeds=BATCHED_SIM_SEEDS):
     """ISSUE-7 acceptance axis: >= 20 vmapped seeds vs the 2-seed Python
     loop, on one 3-day ICO trace, with and without reactive mitigation.
 
     Every run here uses the same lightweight linear predictor (not the
     trained RF), so the rows time the rollout core, not scheduler quality.
-    Two Python-loop baselines are reported: the **pre-PR core** (subprocess,
-    rejection-sampler gamma — what the 2-seed protocol actually cost before
-    this change) and the **current** legacy per-chunk loop, which shares
-    the new Erlang sampler and is therefore already ~20x faster per window.
+    The baseline is the 2-seed protocol on the per-chunk Python loop.
     """
     from repro.core import ICOScheduler
 
@@ -261,10 +216,7 @@ def _batched_axis(out, json_doc, sim_seeds=BATCHED_SIM_SEEDS):
     ref_seed = 11
     quantify = InterferenceQuantifier(lambda x: np.asarray(x)[:, 0] * 0.1)
 
-    legacy = _legacy_baseline_wall()
-
-    # the same 2-seed protocol on the current per-chunk loop (fast=False):
-    # shows how much of the win is the sampler alone
+    # the 2-seed protocol on the per-chunk Python loop (fast=False)
     plan_off: dict = {}
     baseline = []
     t0 = time.time()
@@ -306,23 +258,15 @@ def _batched_axis(out, json_doc, sim_seeds=BATCHED_SIM_SEEDS):
                      if e["sim_seed"] == ref_seed)
     parity_rel = (abs(ref_entry["p99_rt"] - baseline[0].p99_rt)
                   / max(baseline[0].p99_rt, 1e-9))
-    # the ISSUE bar: 20+ vmapped seeds in less wall-clock than the 2-seed
-    # Python loop as it stood before this PR (rejection-sampler core)
-    speedup = legacy["wall_s"] / max(batch_off["wall_s"], 1e-9)
+    # the bar: 20+ vmapped seeds in less wall-clock than the 2-seed loop
+    speedup = python_wall / max(batch_off["wall_s"], 1e-9)
     vmap_per_seed = batch_off["wall_s"] / len(sim_seeds)
     python_per_seed = python_wall / 2
 
     out.append((
-        "control.batched.legacy_baseline", legacy["wall_s"] * 1e6,
-        f"seeds=2;wall_s={legacy['wall_s']:.1f};"
-        f"p99={_mean(legacy['p99']):.2f};core=pre-PR(rejection-gamma)",
-    ))
-    out.append((
         "control.batched.python_loop", python_wall * 1e6,
         f"seeds=2;wall_s={python_wall:.1f};"
-        f"p99={_mean([r.p99_rt for r in baseline]):.2f};"
-        f"core=current(per-chunk+erlang);"
-        f"sampler_speedup={legacy['wall_s'] / max(python_wall, 1e-9):.1f}x",
+        f"p99={_mean([r.p99_rt for r in baseline]):.2f}",
     ))
     out.append((
         "control.batched.vmap", batch_off["wall_s"] * 1e6,
@@ -333,10 +277,10 @@ def _batched_axis(out, json_doc, sim_seeds=BATCHED_SIM_SEEDS):
     ))
     out.append((
         "control.batched.speedup", 0.0,
-        f"prepr_python_2seed_s={legacy['wall_s']:.1f};"
+        f"python_2seed_s={python_wall:.1f};"
         f"vmap_{len(sim_seeds)}seed_s={batch_off['wall_s']:.1f};"
         f"speedup={speedup:.1f}x;"
-        f"faster_than_prepr_python={batch_off['wall_s'] < legacy['wall_s']};"
+        f"faster_than_python={batch_off['wall_s'] < python_wall};"
         f"per_seed_vmap_s={vmap_per_seed:.1f};"
         f"per_seed_python_s={python_per_seed:.1f}",
     ))
@@ -357,24 +301,18 @@ def _batched_axis(out, json_doc, sim_seeds=BATCHED_SIM_SEEDS):
         "sim_seeds": [int(s) for s in sim_seeds],
         "trace": PROACTIVE_TRACE,
         "num_windows": batch_off["num_windows"],
-        "legacy_baseline": {
-            "seeds": [ref_seed, ref_seed + 1],
-            "wall_s": legacy["wall_s"],
-            "p99": legacy["p99"],
-            "core": "pre-PR per-chunk loop + rejection-sampler gamma",
-        },
         "python_baseline": {
             "seeds": [ref_seed, ref_seed + 1],
             "wall_s": python_wall,
             "p99": [r.p99_rt for r in baseline],
-            "core": "current per-chunk loop (erlang sampler)",
+            "core": "per-chunk Python loop",
         },
         "vmap_wall_off_s": batch_off["wall_s"],
         "vmap_wall_on_s": batch_on["wall_s"],
         "vmap_per_seed_s": vmap_per_seed,
         "python_per_seed_s": python_per_seed,
-        "speedup_vs_prepr_python": speedup,
-        "faster_than_prepr_python": batch_off["wall_s"] < legacy["wall_s"],
+        "speedup_vs_python": speedup,
+        "faster_than_python": batch_off["wall_s"] < python_wall,
         "p99_off_mean": _mean(p99_off), "p99_off_std": std(p99_off),
         "p99_on_mean": _mean(p99_on), "p99_on_std": std(p99_on),
         "wins": int(wins), "losses": int(len(sim_seeds) - wins),
@@ -494,9 +432,6 @@ def _proactive_axis(predictor, seeds, out, json_doc, trace_path=None):
 
 def run(fast: bool = True, json_path: str | None = None,
         proactive: bool = False, trace_path: str | None = None):
-    from repro.launch.cache import enable_persistent_cache
-    enable_persistent_cache()  # no-op unless JAX_COMPILATION_CACHE_DIR set
-
     num_placements = 80 if fast else 250
     # (trace_seed, sim_seed) pairs: the acceptance bar is ICO+control
     # beating plain ICO on p99 at >= 2 independent seeds
@@ -528,6 +463,9 @@ def _flag_value(argv, flag, default):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_persistent_cache
+
+    enable_persistent_cache()
     fast = "--full" not in sys.argv
     json_path = _flag_value(sys.argv, "--json", "BENCH_control.json")
     trace_path = _flag_value(sys.argv, "--trace", "BENCH_control_trace.jsonl")

@@ -137,11 +137,8 @@ def run(fast: bool = True, json_path: str | None = None,
         devices: int | None = None):
     import jax
 
-    from repro.launch.cache import enable_persistent_cache
-
-    enable_persistent_cache()  # no-op unless JAX_COMPILATION_CACHE_DIR set
-    ndev = jax.device_count() if devices is None else min(
-        devices, jax.device_count())
+    # asking for more devices than exist raises in batched_rollout
+    ndev = jax.device_count() if devices is None else devices
 
     grid = [(3.0, 12)]
     if not fast:
@@ -209,6 +206,9 @@ def main():
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={int(devices)}")
+    from repro.launch.cache import enable_persistent_cache
+
+    enable_persistent_cache()
     json_path = _flag_value(sys.argv, "--json", "BENCH_rollout_scale.json")
     for row in run(fast="--full" not in sys.argv, json_path=json_path,
                    devices=int(devices) if devices else None):

@@ -82,5 +82,8 @@ def run(fast: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_persistent_cache
+
+    enable_persistent_cache()
     for row in run():
         print(",".join(map(str, row)))

@@ -175,6 +175,9 @@ def _phase_timers(out, windows: int = 30, window_ticks: int = 40):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_persistent_cache
+
+    enable_persistent_cache()
     sweep: dict = {}
     rows = run(fast="--full" not in sys.argv,
                timers="--timers" in sys.argv, sweep_out=sweep)

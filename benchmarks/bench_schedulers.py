@@ -202,6 +202,9 @@ def _flag_value(argv, flag, default):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_persistent_cache
+
+    enable_persistent_cache()
     trace_path = _flag_value(sys.argv, "--trace",
                              "BENCH_schedulers_trace.jsonl")
     json_path = _flag_value(sys.argv, "--json", "BENCH_schedulers.json")

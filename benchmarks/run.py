@@ -4,7 +4,6 @@ from __future__ import annotations
 
 import os
 import sys
-import time
 import traceback
 
 # make `python benchmarks/run.py` equivalent to `python -m benchmarks.run`:
@@ -48,19 +47,26 @@ def main() -> None:
     if "--selftest" in sys.argv:
         sys.exit(selftest())
     from repro.launch.cache import enable_persistent_cache
-    enable_persistent_cache()  # no-op unless JAX_COMPILATION_CACHE_DIR set
+
+    enable_persistent_cache()
     fast = "--full" not in sys.argv
     print("name,us_per_call,derived")
+    failed = []
     for modname in MODULES:
         try:
             mod = __import__(modname, fromlist=["run"])
-            t0 = time.time()
             for name, us, derived in mod.run(fast=fast):
                 print(f"{name},{us:.1f},{derived}")
             sys.stdout.flush()
         except Exception:
+            # keep going so one broken bench does not hide the others'
+            # rows, but the run as a whole fails
+            failed.append(modname)
             print(f"{modname},0,ERROR")
             traceback.print_exc(file=sys.stderr)
+    if failed:
+        print(f"failed: {', '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -417,6 +417,54 @@ def run_experiment(
     )
 
 
+def replay_inputs(plan: dict, sim_seeds, window_ticks: int = 40,
+                  bucket: bool = True) -> dict:
+    """The ``state.batched_rollout`` inputs that replay ``plan`` (a
+    ``run_experiment`` ``plan_out`` dict, or any dict with ``log``,
+    ``t_end``, ``num_nodes`` and optionally ``fleet``) under ``sim_seeds``.
+
+    Returns ``{"state", "profiles", "keys", "events", "fleet"}`` (the
+    call's arguments; ``fleet`` is None for the uniform fleet) plus the
+    trace geometry ``t_end``, ``num_windows``, ``padded_windows`` and
+    ``span`` (ticks per window).
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from repro.cluster import state as cstate
+
+    t_end = int(round(plan["t_end"]))
+    num_nodes = plan["num_nodes"]
+    fleet = plan.get("fleet")
+    total_chunks = t_end // cstate.CHUNK
+    cpw = max(1, window_ticks // cstate.CHUNK)
+    num_windows = -(-total_chunks // cpw)
+    # the control step after the run's last window may act at t_end; such
+    # an entry changes no simulated tick, and when t_end falls on a window
+    # boundary it lies outside the replayed span
+    log = [e for e in plan["log"] if e[1] < t_end]
+    events = cstate.extract_plan(log, 0.0, num_windows, cpw, bucket=bucket)
+    padded_windows = events["op"].shape[0]
+    keys = jnp.stack([
+        cstate.chunk_key_stream(jax.random.PRNGKey(s),
+                                padded_windows * cpw)[1]
+        .reshape(padded_windows, cpw, -1)
+        for s in sim_seeds
+    ])
+    if fleet is not None:
+        state0 = cstate.ClusterState.create(
+            num_nodes, fleet.cores(), fleet.mem_gb())
+        fleet_params = fleet.params()
+    else:
+        state0 = cstate.ClusterState.create(num_nodes)
+        fleet_params = None  # batched_rollout defaults to uniform params
+    profiles = {k: jnp.asarray(v) for k, v in W.online_arrays().items()}
+    return {"state": state0, "profiles": profiles, "keys": keys,
+            "events": events, "fleet": fleet_params, "t_end": t_end,
+            "num_windows": num_windows, "padded_windows": padded_windows,
+            "span": cpw * cstate.CHUNK}
+
+
 def replay_plan_batched(
     plan: dict,
     sim_seeds=tuple(range(20)),
@@ -456,41 +504,17 @@ def replay_plan_batched(
     """
     import time
 
-    import jax
-    import jax.numpy as jnp
-
     from repro.cluster import state as cstate
 
-    t_end = int(round(plan["t_end"]))
-    num_nodes = plan["num_nodes"]
-    fleet = plan.get("fleet")
-    settle_ticks = plan.get("settle_ticks", 40)
-    total_chunks = t_end // cstate.CHUNK
-    cpw = max(1, window_ticks // cstate.CHUNK)
-    num_windows = -(-total_chunks // cpw)
-    span = cpw * cstate.CHUNK
-    events = cstate.extract_plan(plan["log"], 0.0, num_windows, cpw,
-                                 bucket=bucket)
-    padded_windows = events["op"].shape[0]
-    keys = jnp.stack([
-        cstate.chunk_key_stream(jax.random.PRNGKey(s),
-                                padded_windows * cpw)[1]
-        .reshape(padded_windows, cpw, -1)
-        for s in sim_seeds
-    ])
-    if fleet is not None:
-        state0 = cstate.ClusterState.create(
-            num_nodes, fleet.cores(), fleet.mem_gb())
-        fleet_params = fleet.params()
-    else:
-        state0 = cstate.ClusterState.create(num_nodes)
-        fleet_params = None  # batched_rollout defaults to uniform params
-    profiles = {k: jnp.asarray(v) for k, v in W.online_arrays().items()}
+    inp = replay_inputs(plan, sim_seeds, window_ticks, bucket)
+    t_end, settle_ticks = inp["t_end"], plan.get("settle_ticks", 40)
+    num_windows, padded_windows = inp["num_windows"], inp["padded_windows"]
+    span = inp["span"]
 
     t0 = time.time()
-    final, outs = cstate.batched_rollout(state0, profiles, 0.0, keys, events,
-                                         fleet=fleet_params, devices=devices,
-                                         use_pallas=use_pallas)
+    _, outs = cstate.batched_rollout(
+        inp["state"], inp["profiles"], 0.0, inp["keys"], inp["events"],
+        fleet=inp["fleet"], devices=devices, use_pallas=use_pallas)
     rt = np.asarray(outs["rt"])          # (B, W, span, N, S_ON) -> forces sync
     wall_s = time.time() - t0
 

@@ -365,7 +365,7 @@ class Cluster:
 
         s = self.last
         node_hist = s["hist_on"].sum(1) + s["hist_off"].sum(1)  # (N, 200)
-        summaries = np.stack([runqlat_summary(h) for h in node_hist])
+        summaries = runqlat_summary(node_hist)           # (N, 25)
         features = np.concatenate([s["perf"], s["hw"], summaries], axis=1)
         on_active = np.asarray(self.state.on_active)
         # per-slot histograms in detector layout: online slots [0, S_ON),

@@ -74,7 +74,6 @@ accumulated in the carry instead, which is all the detector track needs.
 from __future__ import annotations
 
 import dataclasses
-import os
 from functools import partial
 
 import jax
@@ -82,13 +81,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import metric
-
-# Pre-batched-core compatibility knob: REPRO_GAMMA_REJECTION=1 restores
-# jax.random.gamma's rejection sampler for the runqlat draws, i.e. the old
-# core's dominant cost.  Benchmarks time the old implementation honestly by
-# re-running in a subprocess with this set.  Read once at import — flipping
-# it later would not retrace already-jitted rollout graphs.
-_GAMMA_REJECTION = os.environ.get("REPRO_GAMMA_REJECTION", "") == "1"
 
 S_ON = 8    # online slots per node
 S_OFF = 6   # offline slots per node
@@ -102,7 +94,7 @@ RUNQLAT_BASE = 3.0          # latency units under no contention
 RUNQLAT_SCALE = 55.0        # scale of the delay curve
 RHO_EPS = 0.05              # knee clamp: caps the 1/(1-rho) blow-up
 OVERSUB_SLOPE = 0.15        # thread-oversubscription contention slope
-GAMMA_SHAPE = 2.0
+GAMMA_SHAPE = 2.0           # runqlat draws are Erlang(2): -log(U1*U2)
 
 CHUNK = 10  # fixed inner scan length -> one small shared XLA compilation
 
@@ -556,23 +548,14 @@ def _tick(st: ClusterState, profiles, fleet: FleetParams, t, key):
             jax.random.fold_in(key, 0), active.shape
         )
         mean = delay[:, None] * jnp.maximum(jit_, 0.3)
-        kg = jax.random.fold_in(key, 1)
-        if GAMMA_SHAPE == 2.0 and not _GAMMA_REJECTION:
-            # Gamma(shape=2) is Erlang(2): the sum of two unit
-            # exponentials, sampled exactly as -log(U1*U2).  This replaces
-            # jax.random.gamma's rejection sampler (a lax.while_loop that
-            # costs ~12 ms/call on CPU and serializes under vmap) with two
-            # uniforms and a log -- same distribution, ~100x cheaper, and
-            # the whole tick budget with it.
-            u = jax.random.uniform(
-                kg, (*active.shape, SAMPLES_PER_TICK, 2),
-                minval=jnp.finfo(jnp.float32).tiny, maxval=1.0,
-            )
-            g = -jnp.log(u[..., 0] * u[..., 1])
-        else:  # non-Erlang shapes keep the general sampler
-            g = jax.random.gamma(
-                kg, GAMMA_SHAPE, shape=(*active.shape, SAMPLES_PER_TICK),
-            )
+        # Gamma(shape=2) is Erlang(2): the sum of two unit exponentials,
+        # sampled exactly as -log(U1*U2) -- two uniforms and a log instead
+        # of jax.random.gamma's rejection loop
+        u = jax.random.uniform(
+            jax.random.fold_in(key, 1), (*active.shape, SAMPLES_PER_TICK, 2),
+            minval=jnp.finfo(jnp.float32).tiny, maxval=1.0,
+        )
+        g = -jnp.log(u[..., 0] * u[..., 1])
         samples = g * (mean[..., None] / GAMMA_SHAPE)
         w = jnp.broadcast_to(active[..., None], samples.shape).astype(jnp.float32)
         return samples, w, mean
@@ -1024,7 +1007,7 @@ def _batched_fn(stacked: bool, use_pallas: bool, mesh=None):
     ``mesh=None`` is the single-device vmap; with a 1-D "seeds" mesh the
     identical vmapped scan is wrapped in ``shard_map`` so each host device
     runs its own shard of the batch — seeds never communicate, so the
-    per-seed results are bitwise those of the vmap path (check_rep=False:
+    per-seed results are bitwise those of the vmap path (check_vma=False:
     the replicated inputs are read-only, nothing needs cross-device
     verification).  The stacked state is donated (each seed's carry dies
     into its own final state); the shared state cannot be (a broadcast
@@ -1043,18 +1026,46 @@ def _batched_fn(stacked: bool, use_pallas: bool, mesh=None):
         in_axes=((0 if stacked else None), None, None, None, 0, None, None,
                  None, None))
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
 
         seeds, rep = PartitionSpec("seeds"), PartitionSpec()
-        batched = shard_map(
+        batched = jax.shard_map(
             batched, mesh=mesh,
             in_specs=((seeds if stacked else rep), rep, rep, rep, seeds,
                       rep, rep, rep, rep),
-            out_specs=seeds, check_rep=False)
+            out_specs=seeds, check_vma=False)
     fn = jax.jit(batched, donate_argnums=(0,) if stacked else ())
     _ENGINE_CACHE[cache_key] = fn
     return fn
+
+
+def _batched_call(state, profiles, t0, keys, events, det_cfg, fc_cfg,
+                  fleet, devices, use_pallas):
+    """The executable ``batched_rollout`` runs, its arguments, and the real
+    batch size (the seed axis may be padded to a device multiple)."""
+    det, fc = fold_configs(det_cfg, fc_cfg)
+    batched_state = state.cpu_sum.ndim == 2
+    num_nodes = state.cpu_sum.shape[-1]
+    if fleet is None:
+        fleet = FleetParams.uniform(num_nodes)
+    fold0 = init_fold_state(num_nodes)
+
+    mesh, batch = None, keys.shape[0]
+    if devices is not None and devices > 1:
+        from repro.launch.mesh import make_seed_mesh
+
+        mesh = make_seed_mesh(devices)
+        pad = (-batch) % devices
+        if pad:
+            idx = np.concatenate([np.arange(batch), np.full(pad, batch - 1)])
+            keys = keys[idx]
+            if batched_state:
+                state = jax.tree_util.tree_map(lambda x: x[idx], state)
+
+    fn = _batched_fn(batched_state, use_pallas, mesh)
+    args = (state, profiles, fleet, jnp.float32(t0), keys, events, det, fc,
+            fold0)
+    return fn, args, batch
 
 
 def batched_rollout(state: ClusterState, profiles, t0, keys, events,
@@ -1069,12 +1080,13 @@ def batched_rollout(state: ClusterState, profiles, t0, keys, events,
     events: ``extract_plan`` output, shared across the batch.
     fleet: per-node delay-curve parameters, shared across the batch;
         ``None`` means the homogeneous ``FleetParams.uniform`` fleet.
-    devices: shard the seed axis across this many host devices via
-        ``shard_map`` (clamped to what the runtime exposes; launch with
-        ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` to get more
-        than one on CPU).  The batch is padded to a device multiple by
-        repeating the last seed and the padding is sliced off before
-        returning, so results are bitwise the single-device vmap results.
+    devices: shard the seed axis across this many devices via
+        ``shard_map``.  More devices than the runtime exposes raises
+        (``launch.mesh.make_seed_mesh``); on CPU, launch with
+        ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` to get N.
+        The batch is padded to a device multiple by repeating the last
+        seed and the padding is sliced off before returning, so results
+        are bitwise the single-device vmap results.
     use_pallas: run the fused ``kernels.rollout_tick`` tick kernel instead
         of the default-and-reference jnp tick.
 
@@ -1082,34 +1094,23 @@ def batched_rollout(state: ClusterState, profiles, t0, keys, events,
     per-window RT series (B, W, C*CHUNK, N, S_ON), window-mean qps/cpu/mem,
     and the folded detector's hotspot flags (B, W, N).
     """
-    det, fc = fold_configs(det_cfg, fc_cfg)
-    batched_state = state.cpu_sum.ndim == 2
-    num_nodes = state.cpu_sum.shape[-1]
-    if fleet is None:
-        fleet = FleetParams.uniform(num_nodes)
-    fold0 = init_fold_state(num_nodes)
-
-    mesh, pad, batch = None, 0, keys.shape[0]
-    if devices is not None and devices > 1:
-        from repro.launch.mesh import make_seed_mesh
-
-        mesh = make_seed_mesh(devices)
-        ndev = mesh.devices.size
-        if ndev <= 1:
-            mesh = None
-        else:
-            pad = (-batch) % ndev
-            if pad:
-                idx = np.concatenate(
-                    [np.arange(batch), np.full(pad, batch - 1)])
-                keys = keys[idx]
-                if batched_state:
-                    state = jax.tree_util.tree_map(lambda x: x[idx], state)
-
-    fn = _batched_fn(batched_state, use_pallas, mesh)
-    final, outs = fn(state, profiles, fleet, jnp.float32(t0), keys, events,
-                     det, fc, fold0)
-    if pad:
+    fn, args, batch = _batched_call(state, profiles, t0, keys, events,
+                                    det_cfg, fc_cfg, fleet, devices,
+                                    use_pallas)
+    final, outs = fn(*args)
+    if args[4].shape[0] != batch:
         final = jax.tree_util.tree_map(lambda x: x[:batch], final)
         outs = jax.tree_util.tree_map(lambda x: x[:batch], outs)
     return final, outs
+
+
+def lower_batched_rollout(state: ClusterState, profiles, t0, keys, events,
+                          det_cfg=None, fc_cfg=None,
+                          fleet: FleetParams = None, devices: int = None,
+                          use_pallas: bool = False):
+    """``jax.stages.Lowered`` of the program ``batched_rollout`` runs for
+    these arguments.  ``.compile().as_text()`` is what the device runs; a
+    compiled Pallas kernel shows there as ``tpu_custom_call``."""
+    fn, args, _ = _batched_call(state, profiles, t0, keys, events, det_cfg,
+                                fc_cfg, fleet, devices, use_pallas)
+    return fn.lower(*args)

@@ -13,7 +13,7 @@ a multiple of 128 by the wrapper when necessary.  VMEM footprint per
 program: q tile + 2 kv tiles + accumulator ~= (128 + 2*kv_block) * hd * 4B
 (< 1 MB at kv_block=256, hd=128), far under the ~16 MB budget.
 
-Validated on CPU with interpret=True against ref.py (tests/test_kernels_*).
+Validated on CPU in interpret mode against ref.py (tests/test_kernels.py).
 """
 from __future__ import annotations
 
@@ -23,6 +23,8 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.mode import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -39,8 +41,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, kv_block: int, causal: bool,
 
     def body(j, carry):
         acc, m, l = carry
-        k = pl.load(k_ref, (pl.dslice(j * kv_block, kv_block), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(j * kv_block, kv_block), slice(None)))
+        k = k_ref[pl.ds(j * kv_block, kv_block), :]
+        v = v_ref[pl.ds(j * kv_block, kv_block), :]
         s = jax.lax.dot_general(
             q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -87,7 +89,7 @@ def flash_attention_pallas(
     sliding_window: int = 0,
     q_block: int = 128,
     kv_block: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     B, S, H, hd = q.shape
     q_block = min(q_block, S)
@@ -117,6 +119,6 @@ def flash_attention_pallas(
         ],
         out_specs=pl.BlockSpec((None, q_block, hd), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, hd), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt)
     return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)

@@ -18,10 +18,15 @@ exact random stream of the jnp reference tick):
 * ``act_all`` (N, S) — slot-active mask as f32
 * ``u1``/``u2`` (N, S*K) — Erlang(2) uniforms, K samples per slot
 
-Outputs: node histogram (N, 200), node delay (N, 1), per-slot runqlat
-mean (N, S).  ``fused_tick_reference`` is the same math in plain jnp — the
-unit-parity oracle for interpret mode on CPU (real wins reserved for TPU,
-where the jnp path's HBM round-trips actually cost bandwidth).
+The wrapper repeats ``jit_all``/``act_all`` K times along the lane axis
+before the call, so the kernel body is 2-D elementwise work plus one
+batched contraction, with no reshape Mosaic would have to lower.
+
+The kernel writes the node histogram (N, 200) and node delay (N, 1); the
+wrapper derives the per-slot runqlat mean (N, S) from the delay in XLA.
+``fused_tick_reference`` is the same math in plain jnp — the unit-parity
+oracle for interpret mode on CPU.  Whether the kernel beats the jnp tick
+on the chip has not been measured.
 
 Grid: (N / block,); VMEM per program ~ block * S*K * 200 * 4 bytes for the
 one-hot tile (block=8, S=14, K=16 -> ~1.4 MB).
@@ -35,6 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.metric import BIN_WIDTH, NUM_BINS
+from repro.kernels.mode import resolve_interpret
 
 
 def _node_delay(v, xp=jnp):
@@ -49,34 +55,30 @@ def _node_delay(v, xp=jnp):
     return d * xp.exp(0.13 * noise)
 
 
-def _tick_kernel(nodev_ref, jit_ref, act_ref, u1_ref, u2_ref,
-                 hist_ref, delay_ref, mean_ref, *, gamma_shape, clip_max,
-                 samples_per_slot):
-    block, slots = jit_ref.shape
+def _tick_kernel(nodev_ref, jitx_ref, actx_ref, u1_ref, u2_ref,
+                 hist_ref, delay_ref, *, gamma_shape, clip_max):
     d = jnp.clip(_node_delay(nodev_ref[...]), 0.0, clip_max)  # (block,)
-    mean = d[:, None] * jnp.maximum(jit_ref[...], 0.3)        # (block, S)
 
-    # Erlang(2) == -log(U1 * U2); scaled to the slot mean
-    g = -jnp.log(u1_ref[...] * u2_ref[...])                   # (block, S*K)
-    scale = (mean / gamma_shape)[:, :, None]
-    samples = (g.reshape(block, slots, samples_per_slot)
-               * scale).reshape(block, slots * samples_per_slot)
-    w = jnp.broadcast_to(
-        act_ref[...][:, :, None],
-        (block, slots, samples_per_slot)).reshape(block, -1)
+    # Erlang(2) == -log(U1 * U2), scaled to the slot mean.  The per-slot
+    # jitter and weight arrive already repeated K times along the lanes
+    # (block, S*K): Mosaic cannot split a lane axis into (S, K), and the
+    # elementwise scale keeps the samples bitwise those of the reference.
+    scale = d[:, None] * jnp.maximum(jitx_ref[...], 0.3) / gamma_shape
+    samples = -jnp.log(u1_ref[...] * u2_ref[...]) * scale     # (block, S*K)
 
+    block, width = samples.shape
     idx = jnp.clip(jnp.floor(samples / BIN_WIDTH),
                    0, NUM_BINS - 1).astype(jnp.int32)
     onehot = (idx[:, :, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (block, samples.shape[1], NUM_BINS), 2)
-    ).astype(jnp.float32)
-    # node histogram = weights @ one-hot (MXU contraction over samples)
+        jnp.int32, (block, width, NUM_BINS), 2)).astype(jnp.float32)
+    # node histogram = weights @ one-hot (MXU contraction over samples);
+    # 0/1 operands and integer counts are exact at any matmul precision
     hist = jax.lax.dot_general(
-        w[:, None, :], onehot, (((2,), (1,)), ((0,), (0,))))
+        actx_ref[...][:, None, :], onehot, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
 
     hist_ref[...] = hist[:, 0, :]
     delay_ref[...] = d[:, None]
-    mean_ref[...] = mean
 
 
 @functools.partial(
@@ -84,16 +86,15 @@ def _tick_kernel(nodev_ref, jit_ref, act_ref, u1_ref, u2_ref,
     static_argnames=("gamma_shape", "clip_max", "block", "interpret"))
 def fused_tick(nodev, jit_all, act_all, u1, u2, *, gamma_shape: float = 2.0,
                clip_max: float = 2.5 * (NUM_BINS - 1) * BIN_WIDTH,
-               block: int = 8, interpret: bool = None):
+               block: int = 8, interpret: bool | None = None):
     """Fused delay-curve + Erlang(2) draw + histogram for one tick.
 
-    Returns ``(node_hist (N, 200), delay (N,), mean (N, S))``.  Interpret
-    mode (the CPU default) runs the kernel through the Pallas interpreter,
-    which is what the parity tests exercise; on TPU pass
-    ``interpret=False``.
+    Returns ``(node_hist (N, 200), delay (N,), mean (N, S))``.
+    ``interpret`` follows ``kernels.mode.resolve_interpret``: the Pallas
+    interpreter on the CPU backend (what the parity tests exercise), the
+    compiled Mosaic kernel on TPU.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n, slots = jit_all.shape
     k = u1.shape[1] // slots
     block = min(block, n)
@@ -108,34 +109,35 @@ def fused_tick(nodev, jit_all, act_all, u1, u2, *, gamma_shape: float = 2.0,
         act_all = jnp.pad(act_all, ((0, pad), (0, 0)))
         u1 = jnp.pad(u1, ((0, pad), (0, 0)), constant_values=1.0)
         u2 = jnp.pad(u2, ((0, pad), (0, 0)), constant_values=1.0)
+    jitx = jnp.repeat(jit_all, k, axis=1)   # (N, S*K), slot-major like u1/u2
+    actx = jnp.repeat(act_all, k, axis=1)
 
     kernel = functools.partial(
-        _tick_kernel, gamma_shape=gamma_shape, clip_max=clip_max,
-        samples_per_slot=k)
+        _tick_kernel, gamma_shape=gamma_shape, clip_max=clip_max)
     npad = nodev.shape[0]
-    hist, delay, mean = pl.pallas_call(
+
+    def rows(width):
+        return pl.BlockSpec((block, width), lambda i: (i, 0))
+
+    hist, delay = pl.pallas_call(
         kernel,
         grid=(npad // block,),
-        in_specs=[
-            pl.BlockSpec((block, nodev.shape[1]), lambda i: (i, 0)),
-            pl.BlockSpec((block, slots), lambda i: (i, 0)),
-            pl.BlockSpec((block, slots), lambda i: (i, 0)),
-            pl.BlockSpec((block, slots * k), lambda i: (i, 0)),
-            pl.BlockSpec((block, slots * k), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block, NUM_BINS), lambda i: (i, 0)),
-            pl.BlockSpec((block, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block, slots), lambda i: (i, 0)),
-        ],
+        in_specs=[rows(nodev.shape[1]), rows(slots * k), rows(slots * k),
+                  rows(slots * k), rows(slots * k)],
+        out_specs=[rows(NUM_BINS), rows(1)],
         out_shape=[
             jax.ShapeDtypeStruct((npad, NUM_BINS), jnp.float32),
             jax.ShapeDtypeStruct((npad, 1), jnp.float32),
-            jax.ShapeDtypeStruct((npad, slots), jnp.float32),
         ],
         interpret=interpret,
-    )(nodev, jit_all, act_all, u1, u2)
-    return hist[:n], delay[:n, 0], mean[:n]
+    )(nodev, jitx, actx, u1, u2)
+    delay = delay[:n, 0]
+    # the slot means feed the RT series, which the rollout stacks per tick;
+    # computed here rather than as a kernel output, XLA keeps their layout
+    # free (an (N, S) kernel output pins S to the 128-wide lane axis, and
+    # the stacked RT series inherits that 16x padding)
+    mean = delay[:, None] * jnp.maximum(jit_all[:n], 0.3)
+    return hist[:n], delay, mean
 
 
 def fused_tick_reference(nodev, jit_all, act_all, u1, u2, *,

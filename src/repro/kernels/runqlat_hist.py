@@ -20,6 +20,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.metric import NUM_BINS, BIN_WIDTH
+from repro.kernels.mode import resolve_interpret
 
 
 def _hist_kernel(samples_ref, weights_ref, o_ref, acc_ref):
@@ -46,7 +47,7 @@ def _hist_kernel(samples_ref, weights_ref, o_ref, acc_ref):
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def runqlat_hist_pallas(samples, weights=None, block: int = 512,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """samples: (S_series, N) latencies -> (S_series, 200) histograms."""
     S, N = samples.shape
     if weights is None:
@@ -68,6 +69,6 @@ def runqlat_hist_pallas(samples, weights=None, block: int = 512,
         out_specs=pl.BlockSpec((1, NUM_BINS), lambda s, b: (s, 0)),
         out_shape=jax.ShapeDtypeStruct((S, NUM_BINS), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, NUM_BINS), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(samples, weights)
     return out

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import resolve_interpret
+
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref):
     ci = pl.program_id(1)
@@ -67,7 +69,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref):
 
 @functools.partial(jax.jit, static_argnames=("num_heads", "chunk", "interpret"))
 def wkv_pallas(r, k, v, w, u, num_heads: int, chunk: int = 64,
-               interpret: bool = True):
+               interpret: bool | None = None):
     """r/k/v/w: (B, T, H*P); u: (H, P). Returns y (B, T, H*P)."""
     B, T, HP = r.shape
     H = num_heads
@@ -95,6 +97,6 @@ def wkv_pallas(r, k, v, w, u, num_heads: int, chunk: int = 64,
         out_specs=pl.BlockSpec((1, chunk, P), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, T, P), r.dtype),
         scratch_shapes=[pltpu.VMEM((P, P), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rt, kt, vt, wt, ut)
     return out.reshape(B, H, T, P).transpose(0, 2, 1, 3).reshape(B, T, HP)

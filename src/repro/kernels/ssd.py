@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import resolve_interpret
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_ref):
     ci = pl.program_id(1)
@@ -63,7 +65,8 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_pallas(x, dt, A, B_, C, chunk: int = 64, interpret: bool = True):
+def ssd_pallas(x, dt, A, B_, C, chunk: int = 64,
+               interpret: bool | None = None):
     """x: (B,T,H,P), dt: (B,T,H), A: (H,), B_/C: (B,T,N). Returns y like x."""
     Bsz, T, H, P = x.shape
     N = B_.shape[-1]
@@ -90,6 +93,6 @@ def ssd_pallas(x, dt, A, B_, C, chunk: int = 64, interpret: bool = True):
         out_specs=pl.BlockSpec((1, chunk, P), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((Bsz * H, T, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xt, dtt, at, bt, ct)
     return out.reshape(Bsz, H, T, P).transpose(0, 2, 1, 3)

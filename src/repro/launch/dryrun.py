@@ -29,6 +29,9 @@ from repro.models.sharding import ShardingRules
 from repro.optim import AdamWConfig, init_opt_state
 from repro.train.train_step import make_train_step
 
+# the production meshes are 16x16 pods of this chip (hlo_stats.CHIPS)
+CHIP = "TPU v5e"
+
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "benchmarks", "results", "dryrun")
 
@@ -196,7 +199,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, save: bool = True) -> 
 
     flops_dev = cost.get("flops", 0.0)
     bytes_dev = cost.get("bytes accessed", 0.0)
-    terms = hlo_stats.roofline_terms(flops_dev, bytes_dev, coll["total_bytes"])
+    terms = hlo_stats.roofline_terms(CHIP, flops_dev, bytes_dev,
+                                     coll["total_bytes"])
 
     row.update({
         "status": "ok",

@@ -75,18 +75,29 @@ def count_op(hlo_text: str, opname: str) -> int:
 
 # ------------------------------------------------------- roofline model ---
 
-HW = {
-    "peak_flops": 197e12,   # bf16 FLOP/s per chip (v5e-class)
+# Per-chip peaks, keyed by chip name.  TPU v5e: Google Cloud's published
+# figures ("TPU v5e" documentation): 197 TFLOP/s bf16, 819 GB/s HBM.  The
+# ICI figure is a per-link estimate, not a published peak.  JAX reports
+# the chip as device_kind "TPU v5 lite", so that name maps to the same row.
+_V5E = {
+    "peak_flops": 197e12,   # bf16 FLOP/s per chip
     "hbm_bw": 819e9,        # B/s per chip
-    "ici_bw": 50e9,         # B/s per link
+    "ici_bw": 50e9,         # B/s per link (estimate)
 }
+CHIPS = {"TPU v5e": _V5E, "TPU v5 lite": _V5E}
 
 
-def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
+def roofline_terms(chip: str, flops_per_dev: float, bytes_per_dev: float,
                    coll_bytes_per_dev: float) -> dict:
-    t_compute = flops_per_dev / HW["peak_flops"]
-    t_memory = bytes_per_dev / HW["hbm_bw"]
-    t_collective = coll_bytes_per_dev / HW["ici_bw"]
+    """Roofline times of one program on ``chip``; a chip not in ``CHIPS``
+    raises rather than borrowing another chip's peaks."""
+    if chip not in CHIPS:
+        raise ValueError(f"no peak figures for chip {chip!r}; "
+                         f"known: {sorted(CHIPS)}")
+    hw = CHIPS[chip]
+    t_compute = flops_per_dev / hw["peak_flops"]
+    t_memory = bytes_per_dev / hw["hbm_bw"]
+    t_collective = coll_bytes_per_dev / hw["ici_bw"]
     terms = {
         "t_compute": t_compute,
         "t_memory": t_memory,

@@ -8,6 +8,14 @@ everything else sees the real (single) device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(n: int) -> tuple:
+    # jax.make_mesh defaults to Explicit axes, under which the seed
+    # engine's padded-batch slice and the model layer's sharding rules
+    # raise ShardingTypeError; every mesh here is Auto
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,26 +23,30 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 pods x 256 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_mesh(shape: tuple, axes: tuple):
     """Arbitrary mesh (tests / elastic rescale)."""
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_seed_mesh(num_devices: int = None):
     """1-D "seeds" mesh for sharding a simulation-seed batch axis
     (``cluster.state.batched_rollout(devices=N)``).
 
-    Clamped to the devices the runtime actually exposes — ask for 4 on a
-    plain CPU runtime and you get a 1-device mesh unless the process was
-    launched with ``XLA_FLAGS=--xla_force_host_platform_device_count=4``
-    (set BEFORE importing jax, same rule as the dry-run entrypoint).
+    ``None`` takes every device the runtime exposes.  Asking for more
+    than that raises: a silent smaller mesh would report a 4-chip run
+    that used one.  On CPU, launch with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (set BEFORE
+    importing jax, same rule as the dry-run entrypoint) to get N devices.
     """
     avail = jax.device_count()
-    n = avail if num_devices is None else max(1, min(num_devices, avail))
-    return jax.make_mesh((n,), ("seeds",))
+    n = avail if num_devices is None else num_devices
+    if not 1 <= n <= avail:
+        raise ValueError(f"a {n}-device seed mesh was requested, but the "
+                         f"{jax.default_backend()} runtime exposes {avail}")
+    return jax.make_mesh((n,), ("seeds",), axis_types=_auto(1))
 
 
 def data_axes(mesh) -> tuple:

@@ -14,8 +14,6 @@ tests/test_pipeline.py.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -72,15 +70,10 @@ def gpipe_forward(apply_layer, params_stacked, microbatches, *, mesh,
         outs = jnp.where(s == n_stage - 1, outs, jnp.zeros_like(outs))
         return jax.lax.psum(outs, stage_axis)
 
-    if hasattr(jax, "shard_map"):  # jax >= 0.5
-        smap = partial(jax.shard_map, check_vma=False)
-    else:  # jax 0.4.x: experimental home, and the flag was called check_rep
-        from jax.experimental.shard_map import shard_map
-
-        smap = partial(shard_map, check_rep=False)
-    return smap(
+    return jax.shard_map(
         stage_body,
         mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),
+        check_vma=False,
     )(params_stacked, microbatches)

@@ -62,12 +62,17 @@ def _trees_equal(a, b):
 
 
 def test_shard_request_clamps_to_available_devices():
-    """devices=4 on a single-device runtime falls back to the vmap engine
-    and reproduces it bitwise (the clamp, not a crash, is the contract)."""
+    """devices=4 on a single-device runtime raises instead of clamping:
+    a silent fallback to the vmap engine would pass a one-device run off
+    as a four-device one.  devices=1 is the vmap engine, bitwise."""
     state0, profiles, keys, events = _scenario()
+    assert jax.device_count() == 1
+    with pytest.raises(ValueError, match="4-device seed mesh"):
+        cstate.batched_rollout(state0, profiles, 0.0, keys, events,
+                               devices=4)
     ref = cstate.batched_rollout(state0, profiles, 0.0, keys, events)
     got = cstate.batched_rollout(state0, profiles, 0.0, keys, events,
-                                 devices=4)
+                                 devices=1)
     assert _trees_equal(ref, got)
 
 

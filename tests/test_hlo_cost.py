@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.launch.hlo_cost import HloCostModel, module_cost
-from repro.launch.hlo_stats import roofline_terms, HW
+from repro.launch.hlo_stats import CHIPS, roofline_terms
 
 
 def test_scan_matmul_flops():
@@ -54,13 +54,24 @@ def test_batched_dot_flops():
 
 
 def test_roofline_terms_bottleneck():
-    t = roofline_terms(1e15, 1e9, 1e9)
+    t = roofline_terms("TPU v5e", 1e15, 1e9, 1e9)
     assert t["bottleneck"] == "compute"
-    assert t["t_compute"] == pytest.approx(1e15 / HW["peak_flops"])
-    t = roofline_terms(1e9, 1e13, 1e9)
+    assert t["t_compute"] == pytest.approx(
+        1e15 / CHIPS["TPU v5e"]["peak_flops"])
+    assert CHIPS["TPU v5e"]["peak_flops"] == 197e12
+    assert CHIPS["TPU v5e"]["hbm_bw"] == 819e9
+    t = roofline_terms("TPU v5e", 1e9, 1e13, 1e9)
     assert t["bottleneck"] == "memory"
-    t = roofline_terms(1e9, 1e9, 1e12)
+    t = roofline_terms("TPU v5e", 1e9, 1e9, 1e12)
     assert t["bottleneck"] == "collective"
+
+
+def test_roofline_terms_unknown_chip_raises():
+    # JAX's device_kind for a v5e shares the published row
+    assert roofline_terms("TPU v5 lite", 1e15, 1e9, 1e9) == roofline_terms(
+        "TPU v5e", 1e15, 1e9, 1e9)
+    with pytest.raises(ValueError, match="no peak figures"):
+        roofline_terms("cpu", 1e15, 1e9, 1e9)
 
 
 def test_shape_parsing_tuples():

@@ -14,17 +14,10 @@ from repro.models import model as M
 from repro.models.sharding import ShardingRules
 
 
-def _abstract_mesh(shape, axes):
-    try:
-        return jax.sharding.AbstractMesh(shape, axes)  # jax >= 0.5
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))  # jax 0.4.x
-
-
 def _rules(arch, shape=(4, 4), axes=("data", "model")):
     cfg = get_config(arch)
     # AbstractMesh avoids touching devices
-    mesh = _abstract_mesh(shape, axes)
+    mesh = jax.sharding.AbstractMesh(shape, axes)
     return cfg, ShardingRules(cfg, mesh)
 
 
@@ -78,7 +71,7 @@ SUBPROCESS_PROG = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.configs import get_smoke_config
     from repro.models.sharding import ShardingRules
     from repro.train import make_train_step, init_train_state
@@ -88,7 +81,7 @@ SUBPROCESS_PROG = textwrap.dedent("""
     multi_pod = %(multi_pod)s
     shape = (2, 2, 2) if multi_pod else (2, 4)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    mesh = jax.make_mesh(shape, axes)
+    mesh = jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
     cfg = get_smoke_config("smollm-135m")
     rules = ShardingRules(cfg, mesh)
     params, opt = init_train_state(cfg, jax.random.PRNGKey(0))

@@ -180,6 +180,23 @@ def test_replay_plan_batched_reference_parity():
     assert by_seed[6]["avg_rt"] != by_seed[5]["avg_rt"]
 
 
+def test_replay_ignores_actions_at_run_end():
+    """The control step after a run's last window may act at t_end.  When
+    t_end falls on a window boundary that entry lies past the replayed
+    span; it changes no simulated tick, so the replay is the one without
+    it."""
+    from repro.cluster.experiment import replay_plan_batched
+
+    plan = {"log": [("place_on", 0.0, 0, 0, 0, 300.0, 0.4),
+                    ("place_on", 0.0, 1, 0, 1, 250.0, 0.1)],
+            "t_end": 400.0, "num_nodes": 3}
+    late = dict(plan, log=plan["log"] + [("migrate_on", 400.0, 0, 0, 2, 0)])
+    ref = replay_plan_batched(plan, sim_seeds=[1])
+    got = replay_plan_batched(late, sim_seeds=[1])
+    assert got["seeds"] == ref["seeds"]
+    assert got["num_windows"] == ref["num_windows"] == 10
+
+
 def test_batched_rollout_seed_axis_varies():
     """Two seeds in one vmapped call: same plan, different telemetry."""
     state0 = cstate.ClusterState.create(3)
