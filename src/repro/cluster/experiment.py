@@ -17,6 +17,8 @@ from __future__ import annotations
 import dataclasses
 from collections import deque
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import (
@@ -240,7 +242,8 @@ def run_experiment(
             scheduler.recorder = recorder
     # the loop's timers double as the driver's, so rollout and control
     # phases land in one summary; an uncontrolled run gets its own
-    timers = control_loop.timers if control_loop is not None else PhaseTimers()
+    timers = (control_loop.timers if control_loop is not None
+              else PhaseTimers("repro.loop", jax.profiler.TraceAnnotation))
     stats0 = (0, 0, 0.0, 0.0)
     if control_loop is not None:
         s = control_loop.stats
@@ -313,8 +316,6 @@ def run_experiment(
         The settle phase records RT but not the util series (Figs. 14-15
         average cross-node balance over the arrival phase only).
         """
-        import jax
-
         nonlocal last_view
         stepped = control_loop is not None or forecast is not None
         while ticks > 0:
@@ -417,8 +418,15 @@ def run_experiment(
     )
 
 
+def replay_timers() -> PhaseTimers:
+    """Phase timers for one replay call, each phase a ``repro.replay.*``
+    span on the profiler's clock."""
+    return PhaseTimers("repro.replay", jax.profiler.TraceAnnotation)
+
+
 def replay_inputs(plan: dict, sim_seeds, window_ticks: int = 40,
-                  bucket: bool = True) -> dict:
+                  bucket: bool = True, timers: PhaseTimers | None = None
+                  ) -> dict:
     """The ``state.batched_rollout`` inputs that replay ``plan`` (a
     ``run_experiment`` ``plan_out`` dict, or any dict with ``log``,
     ``t_end``, ``num_nodes`` and optionally ``fleet``) under ``sim_seeds``.
@@ -426,12 +434,13 @@ def replay_inputs(plan: dict, sim_seeds, window_ticks: int = 40,
     Returns ``{"state", "profiles", "keys", "events", "fleet"}`` (the
     call's arguments; ``fleet`` is None for the uniform fleet) plus the
     trace geometry ``t_end``, ``num_windows``, ``padded_windows`` and
-    ``span`` (ticks per window).
+    ``span`` (ticks per window).  Its ``plan`` and ``keys`` phases are
+    timed on ``timers`` (``replay_timers()`` when none is given).
     """
-    import jax
-    import jax.numpy as jnp
-
     from repro.cluster import state as cstate
+
+    if timers is None:
+        timers = replay_timers()
 
     t_end = int(round(plan["t_end"]))
     num_nodes = plan["num_nodes"]
@@ -442,15 +451,18 @@ def replay_inputs(plan: dict, sim_seeds, window_ticks: int = 40,
     # the control step after the run's last window may act at t_end; such
     # an entry changes no simulated tick, and when t_end falls on a window
     # boundary it lies outside the replayed span
-    log = [e for e in plan["log"] if e[1] < t_end]
-    events = cstate.extract_plan(log, 0.0, num_windows, cpw, bucket=bucket)
+    with timers.phase("plan"):
+        log = [e for e in plan["log"] if e[1] < t_end]
+        events = cstate.extract_plan(log, 0.0, num_windows, cpw,
+                                     bucket=bucket)
     padded_windows = events["op"].shape[0]
-    keys = jnp.stack([
-        cstate.chunk_key_stream(jax.random.PRNGKey(s),
-                                padded_windows * cpw)[1]
-        .reshape(padded_windows, cpw, -1)
-        for s in sim_seeds
-    ])
+    with timers.phase("keys"):
+        keys = jnp.stack([
+            cstate.chunk_key_stream(jax.random.PRNGKey(s),
+                                    padded_windows * cpw)[1]
+            .reshape(padded_windows, cpw, -1)
+            for s in sim_seeds
+        ])
     if fleet is not None:
         state0 = cstate.ClusterState.create(
             num_nodes, fleet.cores(), fleet.mem_gb())
@@ -494,61 +506,78 @@ def replay_plan_batched(
     shards the seed axis across host devices (``state.batched_rollout``'s
     shard_map path) and ``use_pallas=True`` runs the fused tick kernel.
 
-    Returns ``{"seeds": [...], "wall_s": float, "num_windows": int,
-    "padded_windows": int}``; each per-seed entry carries avg/p90/p99 RT,
-    arrival-phase cross-node cpu/mem util std (window-level, so not
-    directly comparable with the reference's variable-length control
-    windows), and the folded detector's hot-window count.  Warmup ticks
+    Returns ``{"seeds": [...], "wall_s": float, "phases": dict,
+    "num_windows": int, "padded_windows": int}``; each per-seed entry
+    carries avg/p90/p99 RT, arrival-phase cross-node cpu/mem util std
+    (window-level, so not directly comparable with the reference's
+    variable-length control windows), and the folded detector's
+    hot-window count.  Warmup ticks
     (< 30) and any padding past ``t_end`` are excluded from the RT pool,
     matching the reference driver's sampling span.
-    """
-    import time
 
+    ``phases`` holds the call's seconds by phase, each also a span
+    ``repro.replay.<phase>`` in a profile of the call: ``call`` (all of
+    it), ``inputs`` (``replay_inputs``: ``plan``, the log filter and
+    ``extract_plan``, then ``keys``, the per-seed key streams), ``engine``
+    (``batched_rollout`` until its RT is on the host) and ``reduce`` (the
+    other transfers and the per-seed statistics).  ``wall_s`` is
+    ``phases["engine"]``.
+    """
     from repro.cluster import state as cstate
 
-    inp = replay_inputs(plan, sim_seeds, window_ticks, bucket)
-    t_end, settle_ticks = inp["t_end"], plan.get("settle_ticks", 40)
-    num_windows, padded_windows = inp["num_windows"], inp["padded_windows"]
-    span = inp["span"]
+    timers = replay_timers()
+    with timers.phase("call"):
+        with timers.phase("inputs"):
+            inp = replay_inputs(plan, sim_seeds, window_ticks, bucket,
+                                timers=timers)
+        t_end, settle_ticks = inp["t_end"], plan.get("settle_ticks", 40)
+        num_windows, padded_windows = inp["num_windows"], inp["padded_windows"]
+        span = inp["span"]
 
-    t0 = time.time()
-    _, outs = cstate.batched_rollout(
-        inp["state"], inp["profiles"], 0.0, inp["keys"], inp["events"],
-        fleet=inp["fleet"], devices=devices, use_pallas=use_pallas)
-    rt = np.asarray(outs["rt"])          # (B, W, span, N, S_ON) -> forces sync
-    wall_s = time.time() - t0
+        with timers.phase("engine"):
+            _, outs = cstate.batched_rollout(
+                inp["state"], inp["profiles"], 0.0, inp["keys"],
+                inp["events"], fleet=inp["fleet"], devices=devices,
+                use_pallas=use_pallas)
+            rt = np.asarray(outs["rt"])  # (B, W, span, N, S_ON): syncs
 
-    cpu = np.asarray(outs["cpu_util"])   # (B, W, N)
-    mem = np.asarray(outs["mem_util"])
-    hot = np.asarray(outs["hot"])        # (B, W, N)
-    tick_idx = (np.arange(padded_windows)[:, None] * span
-                + np.arange(span)[None, :])          # (W, span) global tick
-    valid = (tick_idx >= 30) & (tick_idx < t_end)    # skip warmup + padding
-    w_start = np.arange(padded_windows) * span
-    util_wins = (w_start >= 30) & (w_start + span <= t_end - settle_ticks)
-    if not util_wins.any():
-        util_wins = np.ones(padded_windows, bool)    # degenerate short trace
+        with timers.phase("reduce"):
+            cpu = np.asarray(outs["cpu_util"])   # (B, W, N)
+            mem = np.asarray(outs["mem_util"])
+            hot = np.asarray(outs["hot"])        # (B, W, N)
+            tick_idx = (np.arange(padded_windows)[:, None] * span
+                        + np.arange(span)[None, :])  # (W, span) global tick
+            valid = (tick_idx >= 30) & (tick_idx < t_end)  # no warmup/padding
+            w_start = np.arange(padded_windows) * span
+            util_wins = ((w_start >= 30)
+                         & (w_start + span <= t_end - settle_ticks))
+            if not util_wins.any():
+                util_wins = np.ones(padded_windows, bool)  # short trace
 
-    seeds_out = []
-    for i, s in enumerate(sim_seeds):
-        r = rt[i][valid]
-        samples = r[r > 0]
-        if samples.size == 0:
-            samples = np.full(1, np.nan)
-        seeds_out.append({
-            "sim_seed": int(s),
-            "avg_rt": float(samples.mean()),
-            "p90_rt": float(np.percentile(samples, 90)),
-            "p99_rt": float(np.percentile(samples, 99)),
-            "cpu_util_std": float((100 * cpu[i][util_wins]).std(axis=1).mean()),
-            "mem_util_std": float((100 * mem[i][util_wins]).std(axis=1).mean()),
-            # padded windows simulate past t_end and could trip the
-            # detector; only the real prefix counts (it is bitwise the
-            # unbucketed scan's — the fold carry runs front-to-back)
-            "hot_windows": int(hot[i][:num_windows].any(-1).sum()),
-        })
-    return {"seeds": seeds_out, "wall_s": wall_s, "num_windows": num_windows,
-            "padded_windows": padded_windows}
+            seeds_out = []
+            for i, s in enumerate(sim_seeds):
+                r = rt[i][valid]
+                samples = r[r > 0]
+                if samples.size == 0:
+                    samples = np.full(1, np.nan)
+                seeds_out.append({
+                    "sim_seed": int(s),
+                    "avg_rt": float(samples.mean()),
+                    "p90_rt": float(np.percentile(samples, 90)),
+                    "p99_rt": float(np.percentile(samples, 99)),
+                    "cpu_util_std": float(
+                        (100 * cpu[i][util_wins]).std(axis=1).mean()),
+                    "mem_util_std": float(
+                        (100 * mem[i][util_wins]).std(axis=1).mean()),
+                    # padded windows simulate past t_end and could trip the
+                    # detector; only the real prefix counts (it is bitwise
+                    # the unbucketed scan's: the fold carry runs
+                    # front-to-back)
+                    "hot_windows": int(hot[i][:num_windows].any(-1).sum()),
+                })
+    phases = timers.pop_window()
+    return {"seeds": seeds_out, "wall_s": phases["engine"], "phases": phases,
+            "num_windows": num_windows, "padded_windows": padded_windows}
 
 
 def run_experiment_batched(

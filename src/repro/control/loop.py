@@ -54,6 +54,7 @@ import dataclasses
 import weakref
 from collections import deque
 
+import jax
 import numpy as np
 
 from repro.control.actions import Action
@@ -144,7 +145,7 @@ class ControlLoop:
         self.policy = MitigationPolicy(quantifier, self.cfg.policy)
         # counters live here; `loop.stats` assembles the ControlStats view
         self.metrics = MetricsRegistry()
-        self.timers = PhaseTimers()
+        self.timers = PhaseTimers("repro.loop", jax.profiler.TraceAnnotation)
         self.history: deque[dict] = deque(maxlen=self.cfg.history_limit)
         # per-kind multiplicative calibration of predicted_reduction,
         # learned online from post-action verification (1.0 = trust model)
@@ -531,8 +532,6 @@ class ControlLoop:
         rounds a small remainder down to nothing) would loop forever; that
         is an error, not a wait state.
         """
-        import jax
-
         k = k or cluster.CHUNK
         done = 0
         rec = self._recorder

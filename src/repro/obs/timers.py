@@ -1,4 +1,4 @@
-"""Wall-clock phase timers for the control plane.
+"""Wall-clock phase timers for the control plane and the replay.
 
 ``PhaseTimers`` wraps each control-plane phase (rollout / detect /
 forecast / plan / verify) in a ``with timers.phase(name):`` block and
@@ -12,6 +12,16 @@ phase per window is noise next to a jit'd rollout slice — so the
 zero-overhead split applies only to the *event emission*, which happens
 solely when a recorder is attached.
 
+Each phase can also be a span on the profiler's clock: give the
+constructor a factory ``name -> context manager`` (callers that own jax
+pass ``jax.profiler.TraceAnnotation``) and a prefix, and ``phase(name)``
+opens ``annotate(f"{prefix}.{name}")`` around its block.  A phase opened
+inside another is a child span of it.  The factory is injected because
+this package imports no jax.  With the profiler off a ``TraceAnnotation``
+adds about 0.1 us to a phase's 2 us (TPU v5e host); still, open phases
+per window or per call, never per chunk or per tick, and never inside
+traced (jit/scan) code.
+
 Note what a phase time means here: the detector/forecaster/policy phases
 include JAX dispatch and (on first call) compilation, so the first
 window's numbers are dominated by jit warm-up.  ``summary()`` reports
@@ -24,23 +34,29 @@ import time
 
 
 class PhaseTimers:
-    """Named wall-clock accumulators with per-window drain."""
+    """Named wall-clock accumulators with per-window drain, each phase
+    optionally a span named ``{prefix}.{name}`` on the profiler's clock."""
 
-    def __init__(self):
+    def __init__(self, prefix: str = "", annotate=None):
+        self.prefix = prefix
+        self._annotate = annotate
         self.totals: dict[str, float] = {}
         self.counts: dict[str, int] = {}
         self._window: dict[str, float] = {}
 
     @contextlib.contextmanager
     def phase(self, name: str):
-        start = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - start
-            self.totals[name] = self.totals.get(name, 0.0) + dt
-            self.counts[name] = self.counts.get(name, 0) + 1
-            self._window[name] = self._window.get(name, 0.0) + dt
+        span = (contextlib.nullcontext() if self._annotate is None
+                else self._annotate(f"{self.prefix}.{name}"))
+        with span:
+            start = time.perf_counter()
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - start
+                self.totals[name] = self.totals.get(name, 0.0) + dt
+                self.counts[name] = self.counts.get(name, 0) + 1
+                self._window[name] = self._window.get(name, 0.0) + dt
 
     def pop_window(self) -> dict[str, float]:
         """Return and clear the seconds accumulated since the last pop."""

@@ -18,6 +18,7 @@ Four properties carry this layer:
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import subprocess
 import sys
@@ -262,6 +263,48 @@ def test_use_pallas_engine_parity():
 
 
 # ---------------------------------------------------------- phase timers
+
+
+@pytest.mark.parametrize("controlled", [False, True])
+def test_rollout_span_holds_the_device_wait(monkeypatch, controlled):
+    """The structural form of ``test_rollout_phase_attribution``: every
+    ``block_until_ready`` of a ``run_experiment`` runs while the
+    ``repro.loop.rollout`` span is the innermost one open, with or without
+    a control loop, so a profile charges the device wait to the rollout."""
+    from repro.cluster.experiment import _arrival_trace, run_experiment
+    from repro.control import ControlLoop
+    from repro.core import ICOScheduler, InterferenceQuantifier
+
+    open_spans, opened, waits = [], set(), []
+
+    @contextlib.contextmanager
+    def annotate(name, **kwargs):
+        opened.add(name)
+        open_spans.append(name)
+        try:
+            yield
+        finally:
+            open_spans.pop()
+
+    block = jax.block_until_ready
+
+    def spy(x):
+        waits.append(tuple(open_spans))
+        return block(x)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotate)
+    monkeypatch.setattr(jax, "block_until_ready", spy)
+    quant = InterferenceQuantifier(lambda x: np.asarray(x)[:, 0] * 0.1)
+    loop = ControlLoop(quant) if controlled else None
+    pods, gaps = _arrival_trace(6, seed=3)
+    run_experiment(ICOScheduler(quant), pods, gaps, num_nodes=4, seed=5,
+                   fast=True, control_loop=loop, control_window=40)
+    assert waits and all(w[-1:] == ("repro.loop.rollout",) for w in waits)
+    want = {"repro.loop.rollout"}
+    if controlled:
+        want |= {f"repro.loop.{p}"
+                 for p in ("snapshot", "verify", "forecast", "detect")}
+    assert want <= opened, opened
 
 
 def test_rollout_phase_attribution():

@@ -1,12 +1,14 @@
 """Observability layer: trace recorder round-trip, action lifecycle
 chains, admission-breakdown fidelity, the explain CLI, trust-gate events,
 the zero-overhead (recorder-off bit-identical) invariant, the metrics
-registry behind ControlStats, and the bounded history ring buffer.
+registry behind ControlStats, the bounded history ring buffer, and the
+phase timers' spans.
 
 The expensive fixture is ONE seeded 2-day ICO-F + proactive run traced
 end-to-end and serialized/reloaded; every trace-shaped assertion reads
 from that single run.
 """
+import contextlib
 import time
 from collections import Counter as TallyCounter
 
@@ -29,6 +31,7 @@ from repro.obs import (
     Counter,
     MetricsRegistry,
     NULL_RECORDER,
+    PhaseTimers,
     Trace,
     TraceRecorder,
     WindowedHistogram,
@@ -370,3 +373,51 @@ def test_in_memory_trace_matches_loaded_explain(traced_run):
     loaded = explain.explain_pod(trace, uid)
     assert live.splitlines()[0] == loaded.splitlines()[0]
     assert len(live.splitlines()) == len(loaded.splitlines())
+
+
+# ---------------- phase timers as spans ----------------
+
+def _recording_annotation(log):
+    """A span factory that logs each span's entry and exit."""
+    @contextlib.contextmanager
+    def annotate(name, **kwargs):
+        log.append(("enter", name))
+        try:
+            yield
+        finally:
+            log.append(("exit", name))
+    return annotate
+
+
+def test_phase_timers_open_prefixed_nested_spans():
+    log = []
+    timers = PhaseTimers("repro.test", _recording_annotation(log))
+    with timers.phase("outer"):
+        with timers.phase("inner"):
+            pass
+    with pytest.raises(RuntimeError):
+        with timers.phase("inner"):
+            raise RuntimeError("a failing phase still closes its span")
+    assert log == [("enter", "repro.test.outer"),
+                   ("enter", "repro.test.inner"),
+                   ("exit", "repro.test.inner"),
+                   ("exit", "repro.test.outer"),
+                   ("enter", "repro.test.inner"),
+                   ("exit", "repro.test.inner")]
+    # the accumulators keep the bare phase names
+    assert timers.counts == {"outer": 1, "inner": 2}
+    assert set(timers.pop_window()) == {"outer", "inner"}
+    assert set(timers.summary()) == {"outer", "inner"}
+    assert timers.totals["outer"] >= 0.0 and timers.pop_window() == {}
+
+
+def test_phase_timers_without_factory_open_no_span(monkeypatch):
+    import jax
+
+    log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        _recording_annotation(log))
+    timers = PhaseTimers()
+    with timers.phase("rollout"):
+        pass
+    assert log == [] and timers.counts == {"rollout": 1}

@@ -6,6 +6,7 @@ Python loop — same key stream, same telemetry, same placements — so the
 fast paths in `run_experiment` / `replay_plan_batched` measure the same
 simulation the shell-driven runs do.
 """
+import contextlib
 import dataclasses
 
 import numpy as np
@@ -195,6 +196,36 @@ def test_replay_ignores_actions_at_run_end():
     got = replay_plan_batched(late, sim_seeds=[1])
     assert got["seeds"] == ref["seeds"]
     assert got["num_windows"] == ref["num_windows"] == 10
+
+
+def test_replay_phases_are_nested_spans(monkeypatch):
+    """Each replay phase is a ``repro.replay.*`` span, nested as the call
+    runs, and ``phases`` gives the same split on the host's clock."""
+    from repro.cluster.experiment import replay_plan_batched
+
+    log = []
+
+    @contextlib.contextmanager
+    def annotate(name, **kwargs):
+        log.append(("enter", name))
+        try:
+            yield
+        finally:
+            log.append(("exit", name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotate)
+    plan = {"log": [("place_on", 0.0, 0, 0, 0, 300.0, 0.4)],
+            "t_end": 400.0, "num_nodes": 3}
+    out = replay_plan_batched(plan, sim_seeds=[1, 2])
+    steps = ["+call", "+inputs", "+plan", "-plan", "+keys", "-keys",
+             "-inputs", "+engine", "-engine", "+reduce", "-reduce", "-call"]
+    assert log == [("enter" if s[0] == "+" else "exit",
+                    f"repro.replay.{s[1:]}") for s in steps]
+    ph = out["phases"]
+    assert set(ph) == {"call", "inputs", "plan", "keys", "engine", "reduce"}
+    assert out["wall_s"] == ph["engine"]
+    assert ph["call"] >= ph["inputs"] + ph["engine"] + ph["reduce"]
+    assert ph["inputs"] >= ph["plan"] + ph["keys"]
 
 
 def test_batched_rollout_seed_axis_varies():
