@@ -68,7 +68,6 @@ def _synthetic_plan(num_nodes: int, days: float, seed: int = 0):
 
 
 def _build_scenario(num_nodes: int, days: float):
-    import jax
     import jax.numpy as jnp
 
     from repro.cluster import state as cstate
@@ -79,11 +78,8 @@ def _build_scenario(num_nodes: int, days: float):
     num_windows = -(-(t_end // cstate.CHUNK) // cpw)
     events = cstate.extract_plan(log, 0.0, num_windows, cpw)
     seeds = SIM_SEEDS if num_nodes <= 100 else SAMPLE_SEEDS
-    keys = jnp.stack([
-        cstate.chunk_key_stream(jax.random.PRNGKey(s), num_windows * cpw)[1]
-        .reshape(num_windows, cpw, -1)
-        for s in seeds
-    ])
+    keys = cstate.replay_key_stream(cstate.seed_keys(seeds), num_windows,
+                                    cpw)
     state0 = cstate.ClusterState.create(num_nodes)
     profiles = {k: jnp.asarray(v) for k, v in W.online_arrays().items()}
     return dict(state0=state0, profiles=profiles, keys=keys, events=events,

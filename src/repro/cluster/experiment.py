@@ -434,8 +434,13 @@ def replay_inputs(plan: dict, sim_seeds, window_ticks: int = 40,
     Returns ``{"state", "profiles", "keys", "events", "fleet"}`` (the
     call's arguments; ``fleet`` is None for the uniform fleet) plus the
     trace geometry ``t_end``, ``num_windows``, ``padded_windows`` and
-    ``span`` (ticks per window).  Its ``plan`` and ``keys`` phases are
-    timed on ``timers`` (``replay_timers()`` when none is given).
+    ``span`` (ticks per window).  ``keys`` is every seed's chunk key
+    stream, (B, padded_windows, cpw, 2), built by two compiled programs
+    (``state.seed_keys``, ``state.replay_key_stream``) and bitwise the
+    eager per-seed split loop; the stream is prefix-stable, so padding
+    windows leaves the real windows' keys as they are.  Its ``plan`` and
+    ``keys`` phases are timed on ``timers`` (``replay_timers()`` when none
+    is given); ``keys`` waits for the stream.
     """
     from repro.cluster import state as cstate
 
@@ -457,12 +462,9 @@ def replay_inputs(plan: dict, sim_seeds, window_ticks: int = 40,
                                      bucket=bucket)
     padded_windows = events["op"].shape[0]
     with timers.phase("keys"):
-        keys = jnp.stack([
-            cstate.chunk_key_stream(jax.random.PRNGKey(s),
-                                    padded_windows * cpw)[1]
-            .reshape(padded_windows, cpw, -1)
-            for s in sim_seeds
-        ])
+        keys = cstate.replay_key_stream(cstate.seed_keys(sim_seeds),
+                                        padded_windows, cpw)
+        keys.block_until_ready()
     if fleet is not None:
         state0 = cstate.ClusterState.create(
             num_nodes, fleet.cores(), fleet.mem_gb())
@@ -518,7 +520,7 @@ def replay_plan_batched(
     ``phases`` holds the call's seconds by phase, each also a span
     ``repro.replay.<phase>`` in a profile of the call: ``call`` (all of
     it), ``inputs`` (``replay_inputs``: ``plan``, the log filter and
-    ``extract_plan``, then ``keys``, the per-seed key streams), ``engine``
+    ``extract_plan``, then ``keys``, the compiled key stream), ``engine``
     (``batched_rollout`` until its RT is on the host) and ``reduce`` (the
     other transfers and the per-seed statistics).  ``wall_s`` is
     ``phases["engine"]``.

@@ -702,19 +702,42 @@ def _rollout_chunks_impl(state: ClusterState, profiles, fleet, t0, keys):
 rollout_chunks = jax.jit(_rollout_chunks_impl, donate_argnums=(0,))
 
 
+@partial(jax.jit, static_argnames=("num_chunks",))
 def chunk_key_stream(key, num_chunks: int):
     """Replicate ``Cluster.rollout``'s iterative per-chunk key splits.
 
-    Returns (advanced_key, (num_chunks, 2) stacked chunk keys).  The stream
-    is prefix-stable: the first k keys for a given seed never change as
-    more chunks are requested, which is what lets a batched replay reuse
-    the reference run's exact randomness.
+    Returns (advanced_key, (num_chunks, 2) stacked chunk keys): a
+    ``lax.scan`` of ``key, k = jax.random.split(key)``, one compiled
+    program per chunk count, bitwise the eager split loop.  The stream is
+    prefix-stable: the first k keys for a given seed never change as more
+    chunks are requested, which is what lets a batched replay reuse the
+    reference run's exact randomness.
     """
-    ks = []
-    for _ in range(num_chunks):
-        key, k = jax.random.split(key)
-        ks.append(k)
-    return key, jnp.stack(ks)
+    def body(k, _):
+        k, sub = jax.random.split(k)
+        return k, sub
+
+    return jax.lax.scan(body, key, None, length=num_chunks)
+
+
+def seed_keys(sim_seeds) -> jax.Array:
+    """(B, 2) keys, row i bitwise ``jax.random.PRNGKey(int(sim_seeds[i]))``,
+    from one program.  The seeds go over as int64, as ``PRNGKey`` converts
+    a Python int, so seeds of 2**31 and above key the same way."""
+    seeds = np.asarray([int(s) for s in sim_seeds], np.int64)
+    return _seed_keys(jnp.asarray(seeds))
+
+
+_seed_keys = jax.jit(jax.vmap(jax.random.PRNGKey))
+
+
+@partial(jax.jit, static_argnames=("num_windows", "cpw"))
+def replay_key_stream(keys, num_windows: int, cpw: int):
+    """(B, num_windows, cpw, 2) replay chunk keys from (B, 2) seed keys:
+    each row's ``chunk_key_stream`` of ``num_windows * cpw`` chunks, every
+    seed in one program, window by window."""
+    ks = jax.vmap(lambda k: chunk_key_stream(k, num_windows * cpw)[1])(keys)
+    return ks.reshape(keys.shape[0], num_windows, cpw, ks.shape[-1])
 
 
 def merge_summaries(parts: list[dict]):

@@ -245,3 +245,87 @@ def test_batched_rollout_seed_axis_varies():
     # the plan (occupancy) is identical across the seed axis
     np.testing.assert_array_equal(np.asarray(final["state"].on_active)[0],
                                   np.asarray(final["state"].on_active)[1])
+
+
+def _eager_chunk_key_stream(key, num_chunks):
+    """The split loop ``chunk_key_stream`` replaced: one eager dispatch a
+    chunk, then a stack.  The reference for its bits."""
+    ks = []
+    for _ in range(num_chunks):
+        key, k = jax.random.split(key)
+        ks.append(k)
+    return key, jnp.stack(ks)
+
+
+def _eager_replay_keys(sim_seeds, num_windows, cpw):
+    return jnp.stack([
+        _eager_chunk_key_stream(jax.random.PRNGKey(int(s)),
+                                num_windows * cpw)[1]
+        .reshape(num_windows, cpw, -1)
+        for s in sim_seeds])
+
+
+KEY_SEEDS = (0, 7, 2**31 - 1, 3_000_000_017)
+
+
+@pytest.mark.parametrize("count", [1, 4, 1024])
+@pytest.mark.parametrize("seed", KEY_SEEDS)
+def test_chunk_key_stream_matches_eager_loop(seed, count):
+    key = jax.random.PRNGKey(seed)
+    want_key, want = _eager_chunk_key_stream(key, count)
+    got_key, got = cstate.chunk_key_stream(key, count)
+    assert got.shape == (count, 2)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got_key), np.asarray(want_key))
+
+
+@pytest.mark.parametrize("num_windows,cpw", [(1, 1), (2, 2), (256, 4)])
+def test_replay_key_stream_matches_eager_loop(num_windows, cpw):
+    """Every seed's stream in one program, seeds of 2**31 and above
+    included, is bitwise the per-seed eager loop."""
+    sk = cstate.seed_keys(KEY_SEEDS)
+    np.testing.assert_array_equal(
+        np.asarray(sk),
+        np.stack([np.asarray(jax.random.PRNGKey(s)) for s in KEY_SEEDS]))
+    got = cstate.replay_key_stream(sk, num_windows, cpw)
+    assert got.shape == (len(KEY_SEEDS), num_windows, cpw, 2)
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        np.asarray(_eager_replay_keys(KEY_SEEDS, num_windows, cpw)))
+
+
+_KEY_PLAN = {"log": [("place_on", 0.0, 0, 0, 0, 300.0, 0.4),
+                     ("place_on", 0.0, 1, 0, 1, 250.0, 0.1),
+                     ("migrate_on", 400.0, 0, 0, 2, 0)],
+             "t_end": 800.0, "num_nodes": 3}
+
+
+def test_replay_inputs_keys_match_eager_stack():
+    from repro.cluster.experiment import replay_inputs
+
+    seeds = tuple(range(100, 120))
+    inp = replay_inputs(_KEY_PLAN, seeds)
+    cpw = inp["span"] // CHUNK
+    want = _eager_replay_keys(seeds, inp["padded_windows"], cpw)
+    np.testing.assert_array_equal(np.asarray(inp["keys"]), np.asarray(want))
+
+
+def test_replay_compiled_keys_match_eager_replay(monkeypatch):
+    """The replay's answers are bitwise those of the eager key stream, and
+    building the keys is now a small part of a call."""
+    from repro.cluster.experiment import replay_plan_batched
+
+    seeds = tuple(range(20))
+    replay_plan_batched(_KEY_PLAN, sim_seeds=seeds)        # compiles
+    new = replay_plan_batched(_KEY_PLAN, sim_seeds=seeds)
+    monkeypatch.setattr(
+        cstate, "replay_key_stream",
+        lambda sk, num_windows, cpw: _eager_replay_keys(seeds, num_windows,
+                                                        cpw))
+    old = replay_plan_batched(_KEY_PLAN, sim_seeds=seeds)
+    for g, w in zip(new["seeds"], old["seeds"], strict=True):
+        for k in ("sim_seed", "avg_rt", "p90_rt", "p99_rt", "hot_windows"):
+            assert g[k] == w[k], k
+    ph = new["phases"]
+    assert ph["keys"] < 0.05 * ph["call"]
+    assert ph["keys"] < 0.1 * old["phases"]["keys"]
