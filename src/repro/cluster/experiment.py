@@ -14,6 +14,7 @@ ticks instead of dropping them permanently.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from collections import deque
 
@@ -51,6 +52,12 @@ class ExperimentResult:
     proactive_mitigations: int = 0    # subset planned from forecast drift
     predicted_reduction: float = 0.0  # cost-model claim for this run's actions
     realized_reduction: float = 0.0   # what post-action verification observed
+    offers: int = 0           # select_node calls, retries included
+    offers_rejected: int = 0  # offers that placed nothing
+    # this run's seconds by phase: the loop's rollout/snapshot/verify/
+    # forecast/detect/plan and the scheduler's admit.* (see run_experiment);
+    # a timing, not a result, so two runs' results compare without it
+    phases: dict = dataclasses.field(default_factory=dict, compare=False)
 
 
 def train_default_predictor(seed: int = 0, num_placements: int = 250):
@@ -227,6 +234,14 @@ def run_experiment(
         (the cluster's mutation log + trace geometry) for
         ``replay_plan_batched`` — the vmapped many-seed re-evaluation of
         this exact placement/action schedule.
+
+    The result's ``phases`` are this run's seconds by phase, each also a
+    ``repro.loop.<phase>`` or ``repro.admit.<phase>`` span: ``rollout``
+    and ``snapshot`` (the driver's), ``verify``/``forecast``/``detect``/
+    ``plan`` (the control loop's) and, for a scheduler with ``timers``,
+    ``admit.<phase>`` (its own phases, plus ``place``: ``Cluster.place``).
+    ``offers`` counts the scheduler's calls, retries included, and
+    ``offers_rejected`` those that placed nothing.
     """
     if control_loop is not None and not hasattr(control_loop, "step"):
         control_loop = control_loop()  # factory -> fresh per-run instance
@@ -244,6 +259,9 @@ def run_experiment(
     # phases land in one summary; an uncontrolled run gets its own
     timers = (control_loop.timers if control_loop is not None
               else PhaseTimers("repro.loop", jax.profiler.TraceAnnotation))
+    admit = getattr(scheduler, "timers", None)
+    totals0 = {"": dict(timers.totals),
+               "admit.": dict(admit.totals) if admit is not None else {}}
     stats0 = (0, 0, 0.0, 0.0)
     if control_loop is not None:
         s = control_loop.stats
@@ -253,12 +271,13 @@ def run_experiment(
     num_nodes = cluster.n  # fleet overrides the scalar argument
     use_scan = fast if fast is not None else (recorder is None)
     roll = cluster.rollout_scan if use_scan else cluster.rollout
-    roll(30)
+    with timers.phase("rollout"):
+        jax.block_until_ready((roll(30), cluster.state.cpu_sum))
     if recorder is not None:
         recorder.begin_window(cluster.t)
     rt_all: list[np.ndarray] = []
     cpu_series, mem_series = [], []
-    placed = rejected = queued_retries = 0
+    placed = rejected = queued_retries = offers = offers_rejected = 0
     retry_q: deque[tuple[Pod, int]] = deque()  # (pod, attempts so far)
     last_view = None  # advance()'s final window view, reusable at the same t
 
@@ -272,15 +291,22 @@ def run_experiment(
         if last_view is not None and last_view.t == cluster.t:
             view = last_view
         else:
-            view = cluster.view()
+            with timers.phase("snapshot"):
+                view = cluster.view()
         if forecast is not None:
-            forecast.observe(view)   # idempotent if advance() already did
-            forecast.annotate(view)
+            with timers.phase("forecast"):
+                forecast.observe(view)  # idempotent if advance() already did
+                forecast.annotate(view)
         return view
 
     def offer(pod: Pod, view, retry: bool = False) -> bool:
+        nonlocal offers, offers_rejected
         node = scheduler.select_node(pod, view)
-        ok = node >= 0 and cluster.place(pod, node)
+        with (admit.phase("place") if admit is not None
+              else contextlib.nullcontext()):
+            ok = node >= 0 and cluster.place(pod, node)
+        offers += 1
+        offers_rejected += int(not ok)
         if recorder is not None:
             # the uid exists only after a successful place; bind it (and the
             # outcome) onto the admission event the scheduler just emitted
@@ -392,6 +418,10 @@ def run_experiment(
         proactive = s.proactive_applied - stats0[1]
         predicted = s.predicted_reduction - stats0[2]
         realized = s.realized_reduction - stats0[3]
+    phases = {}
+    for prefix, t in (("", timers), ("admit.", admit)):
+        for k, v in (t.totals.items() if t is not None else ()):
+            phases[prefix + k] = v - totals0[prefix].get(k, 0.0)
     if plan_out is not None:
         plan_out.update(
             log=list(cluster.log),
@@ -415,6 +445,9 @@ def run_experiment(
         proactive_mitigations=proactive,
         predicted_reduction=predicted,
         realized_reduction=realized,
+        offers=offers,
+        offers_rejected=offers_rejected,
+        phases=phases,
     )
 
 

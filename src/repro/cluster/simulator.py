@@ -388,6 +388,7 @@ class Cluster:
             node_class = None
         return ClusterView(
             t=float(self.t),
+            window_ticks=int(s["rt"].shape[0]),
             cpu_cur=s["cpu_demand"],
             cpu_sum=np.asarray(self.state.cpu_sum),
             mem_cur=s["mem_used"],

@@ -44,6 +44,8 @@ class ClusterView:
     """
 
     t: float = 0.0                                # cluster clock (ticks)
+    window_ticks: int | None = None               # ticks the window spans,
+                                                  # ending at t
     cpu_cur: np.ndarray | None = None             # (N,) window-mean CPU demand
     cpu_sum: np.ndarray | None = None             # (N,) node CPU capacity
     mem_cur: np.ndarray | None = None             # (N,) window-mean MEM used

@@ -184,6 +184,24 @@ def _detector_update(hist, mu, cusum, f_cusum, slot_hist, slot_prev,
             steps + 1, hot, proactive, diag)
 
 
+def slot_mask(shape, nodes, slots) -> np.ndarray:
+    """(N, S) bool mask of the (node, slot) pairs, built on the host: a
+    masked update of this fixed shape compiles once, where a scatter
+    compiles once per index count."""
+    mask = np.zeros(shape, bool)
+    mask[nodes, slots] = True
+    return mask
+
+
+@jax.jit
+def _clear_slot_track(slot_hist, slot_prev, slot_score, mask):
+    """Zero the slot track where ``mask`` is set (bitwise the scatter of
+    0.0 into those slots)."""
+    return (jnp.where(mask[..., None], 0.0, slot_hist),
+            jnp.where(mask, 0.0, slot_prev),
+            jnp.where(mask, 0.0, slot_score))
+
+
 class StreamingDetector:
     """Host-side wrapper owning the detector state for one cluster."""
 
@@ -233,10 +251,9 @@ class StreamingDetector:
         slots = np.asarray(slots, np.int64).ravel()
         if nodes.size == 0:
             return
-        idx = (jnp.asarray(nodes), jnp.asarray(slots))
-        self.slot_hist = self.slot_hist.at[idx].set(0.0)
-        self.slot_prev = self.slot_prev.at[idx].set(0.0)
-        self.slot_score = self.slot_score.at[idx].set(0.0)
+        mask = slot_mask(self.slot_prev.shape, nodes, slots)
+        self.slot_hist, self.slot_prev, self.slot_score = _clear_slot_track(
+            self.slot_hist, self.slot_prev, self.slot_score, mask)
         if self.slot_scores is not None:
             scores = np.array(self.slot_scores)  # may be a read-only view
             scores[nodes, slots] = 0.0

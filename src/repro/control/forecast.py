@@ -55,6 +55,7 @@ import numpy as np
 
 from repro.cluster import simulator as sim
 from repro.cluster.workloads import online_arrays
+from repro.control.detector import slot_mask
 from repro.control.policy import node_delay_curve, view_delay_params
 
 NUM_FEATURES = 5  # [1, sin wt, cos wt, sin 2wt, cos 2wt]
@@ -145,6 +146,16 @@ def _leverage(A, t_future, ridge):
     return (xb * _solve(A, xb, ridge)).sum(-1)
 
 
+@jax.jit
+def _clear_fits(A, b, err, count, mask):
+    """Reset the fits where the (N, S) ``mask`` is set: one program for
+    every number of cleared slots, bitwise the scatter it replaces."""
+    return (jnp.where(mask[..., None, None], 0.0, A),
+            jnp.where(mask[..., None], 0.0, b),
+            jnp.where(mask, 1.0, err),
+            jnp.where(mask, 0, count))
+
+
 class QPSForecaster:
     """Host-side wrapper owning per-(node, slot) forecast state."""
 
@@ -171,11 +182,9 @@ class QPSForecaster:
         slots = np.asarray(slots, np.int64).ravel()
         if nodes.size == 0:
             return
-        idx = (jnp.asarray(nodes), jnp.asarray(slots))
-        self.A = self.A.at[idx].set(0.0)
-        self.b = self.b.at[idx].set(0.0)
-        self.err = self.err.at[idx].set(1.0)
-        self.count = self.count.at[idx].set(0)
+        mask = slot_mask((self.n, self.s), nodes, slots)
+        self.A, self.b, self.err, self.count = _clear_fits(
+            self.A, self.b, self.err, self.count, mask)
 
     def update(self, t: float, qps, active) -> np.ndarray:
         """Feed one window's mean QPS; returns the one-step EWMA errors."""

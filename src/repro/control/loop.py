@@ -160,6 +160,10 @@ class ControlLoop:
         # cfg.horizon are silently unused
         self._external_forecast = forecast_service
         self._recorder = recorder
+        # opt-in outcome records: set to a list and every step appends its
+        # window's flags and, per flagged node, the actions taken or the
+        # guard that declined them (``_record_outcomes``)
+        self.outcomes: list[dict] | None = None
         self.reset()
 
     @property
@@ -428,6 +432,8 @@ class ControlLoop:
         proactive_mask &= actionable
 
         applied: list[Action] = []
+        plan: list[Action] = []
+        declined = {} if self.outcomes is not None else None
         if actionable.any() and step_no % self.cfg.interval == 0:
             recently_acted = frozenset(
                 uid for uid, step in self._uid_last_acted.items()
@@ -440,7 +446,7 @@ class ControlLoop:
                                         attribution=self.detector.attribution(),
                                         proactive=proactive_mask,
                                         forecast_pressure=forecast_rho,
-                                        recorder=rec)
+                                        recorder=rec, declined=declined)
             m.inc("actions_planned", len(plan))
             for action in plan:
                 if action.apply(cluster):
@@ -478,6 +484,9 @@ class ControlLoop:
                             predicted_reduction=action.predicted_reduction))
             for node in {a.node for a in applied if not a.proactive}:
                 self._verify_sig[node] = self._node_signature(cluster, node)
+        if self.outcomes is not None:
+            self._record_outcomes(step_no, view, hot, pro, actionable, plan,
+                                  applied, declined)
         if hot.any() or pro.any() or applied or verified:
             self.history.append({
                 "step": step_no,
@@ -490,6 +499,50 @@ class ControlLoop:
                 "verified": verified,
             })
         return applied
+
+    def _record_outcomes(self, step_no: int, view, hot, pro, actionable,
+                         plan: list[Action], applied: list[Action],
+                         declined: dict) -> None:
+        """Append this window's outcome record to ``outcomes``.
+
+        ``hot``/``proactive`` are the detector's flags; ``flagged`` holds,
+        for each flagged node, the actions planned on it (kind, cost,
+        whether ``apply`` took) and, when none took, the guard that
+        declined it: ``cooldown`` (acted on at step ``last_acted``, under
+        ``cooldown`` steps ago), ``interval`` (not an acting step),
+        ``apply_failed``, or the policy's guard (``MitigationPolicy.plan``'s
+        ``declined``).  ``spent`` is the cost of everything planned.
+        """
+        took = {id(a) for a in applied}
+        flagged = []
+        for node in np.nonzero(hot | pro)[0]:
+            node = int(node)
+            acts = [{"kind": a.kind, "cost": float(a.cost),
+                     "proactive": bool(a.proactive), "applied": id(a) in took}
+                    for a in plan if a.node == node]
+            entry = {"node": node,
+                     "channel": "hot" if hot[node] else "proactive",
+                     "actions": acts, "guard": None}
+            if any(a["applied"] for a in acts):
+                pass  # handled: no guard to name
+            elif not actionable[node]:
+                entry.update(guard="cooldown",
+                             last_acted=self._last_acted[node])
+            elif step_no % self.cfg.interval:
+                entry["guard"] = "interval"
+            elif acts:
+                entry["guard"] = "apply_failed"
+            else:
+                entry.update(declined.get(node, {}))
+            flagged.append(entry)
+        self.outcomes.append({
+            "step": step_no, "t": float(view.t),
+            "window_ticks": view.window_ticks,
+            "hot": np.nonzero(hot)[0].tolist(),
+            "proactive": np.nonzero(pro)[0].tolist(),
+            "spent": float(sum(a.cost for a in plan)),
+            "flagged": flagged,
+        })
 
     def _emit_hotspots(self, hot: np.ndarray, pro: np.ndarray) -> None:
         """One HotspotFlag per flagged node, from the detector diagnostics.

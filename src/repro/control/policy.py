@@ -171,7 +171,8 @@ class MitigationPolicy:
 
     def plan(self, cluster, view, hot, exclude_uids=frozenset(),
              corrections=None, attribution=None, proactive=None,
-             forecast_pressure=None, recorder=None) -> list[Action]:
+             forecast_pressure=None, recorder=None,
+             declined: dict | None = None) -> list[Action]:
         """view: the ``repro.cluster.ClusterView`` telemetry snapshot.
         exclude_uids: pods recently acted on (per-pod anti-ping-pong).
         corrections: per-kind multiplicative calibration of
@@ -189,6 +190,11 @@ class MitigationPolicy:
         recorder: optional ``repro.obs.TraceRecorder``; each chosen action
             gets an ``action_id`` and an ``ActionPlanned`` event recording
             the greedy ranking it won (correction applied, net gain, rank).
+        declined: optional dict, filled with the guard that left each
+            ``hot`` node without an action: ``no_candidate`` (no action to
+            offer), ``net_gain`` (none buys more than it costs; with the
+            ``best_net_gain``) or ``budget`` (the best one did not fit: the
+            ``spent`` before it, its ``cost`` and the ``budget``).
         """
         hot = np.asarray(hot, bool)
         corrections = corrections or {}
@@ -210,12 +216,16 @@ class MitigationPolicy:
             calibrated = corrections.get(a.kind, 1.0) * a.predicted_reduction
             return calibrated - self.cfg.cost_weight * a.cost
 
+        offered = candidates
         candidates = [a for a in candidates if net_gain(a) > 0]
         candidates.sort(key=net_gain, reverse=True)
         chosen, spent, per_node = [], 0.0, {}
         used_uids: set[int] = set()
+        over_budget: dict[int, dict] = {}
         for a in candidates:
             if spent + a.cost > self.cfg.budget:
+                over_budget.setdefault(a.node, {"spent": spent,
+                                                "cost": a.cost})
                 continue
             if per_node.get(a.node, 0) >= self.cfg.max_actions_per_node:
                 continue
@@ -228,6 +238,21 @@ class MitigationPolicy:
             spent += a.cost
             per_node[a.node] = per_node.get(a.node, 0) + 1
             used_uids.add(uid)
+        if declined is not None:
+            acted = {a.node for a in chosen}
+            for node in np.nonzero(hot)[0]:
+                node = int(node)
+                gains = [net_gain(a) for a in offered if a.node == node]
+                if node in acted:
+                    continue
+                if not gains:
+                    declined[node] = {"guard": "no_candidate"}
+                elif node in over_budget:
+                    declined[node] = {"guard": "budget", **over_budget[node],
+                                      "budget": self.cfg.budget}
+                else:
+                    declined[node] = {"guard": "net_gain",
+                                      "best_net_gain": max(gains)}
         if recorder:
             from repro.obs import ActionPlanned
             for rank, a in enumerate(chosen):

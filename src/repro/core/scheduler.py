@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.interference import INTF_NORM
+from repro.obs import PhaseTimers
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,7 +75,19 @@ def _score_nodes(
 
 
 class ICOScheduler:
-    """Interference-aware Container Orchestration scheduler (Algorithm 1)."""
+    """Interference-aware Container Orchestration scheduler (Algorithm 1).
+
+    Each offer runs in three phases, timed on ``timers`` and opened as
+    ``repro.admit.<phase>`` spans once per offer: ``topk`` (the candidate
+    prefilter, past ``candidate_k`` nodes only), ``quantify`` (Eq. 1's
+    ``intf_nodes`` and the Eq. 3 predictor behind ``intf_pod``, plus
+    ICO-F's forecast term) and ``score`` (the jit'd Eqs. 4-6 argmax).
+
+    ``decisions`` is an opt-in decision log: set it to a list and every
+    offer appends one entry holding what its choice used (see
+    ``_decision``); ``None`` (the default) keeps nothing.  A
+    ``recorder``'s ``AdmissionDecision`` is built from the same entry.
+    """
 
     name = "ICO"
 
@@ -84,65 +97,71 @@ class ICOScheduler:
         self.recorder = None  # optional repro.obs.TraceRecorder: when set,
                               # select_node emits an AdmissionDecision with
                               # the per-node Eq. (4)-(6) breakdown
-
-    def _interference(self, pod, view):
-        """(intf_h, intf_p) for Eq. (4) — the hook ICO-F augments."""
-        intf_h = self.q.intf_nodes(view.online_hists, view.offline_hists)
-        intf_p = self.q.intf_pod(pod.qps, view.features)
-        return intf_h, intf_p
+        self.decisions: list[dict] | None = None
+        self.timers = PhaseTimers("repro.admit", jax.profiler.TraceAnnotation)
 
     def _forecast_term(self, view):
-        """Per-node forecast addend to ``intf_h`` (None for plain ICO)."""
+        """Per-node forecast addend to ``intf_h`` (None for plain ICO) —
+        the hook ICO-F overrides."""
         return None
 
     def _score(self, pod, view):
-        if view.num_nodes > self.cfg.candidate_k:
-            return self._score_topk(pod, view)
-        return self._score_exact(pod, view)
-
-    def _score_exact(self, pod, view):
-        intf_h, intf_p = self._interference(pod, view)
-        return _score_nodes(
-            jnp.asarray(view.cpu_cur, jnp.float32),
-            jnp.asarray(view.cpu_sum, jnp.float32),
-            jnp.asarray(view.mem_cur, jnp.float32),
-            jnp.asarray(view.mem_sum, jnp.float32),
-            jnp.asarray(intf_h, jnp.float32),
-            jnp.asarray(intf_p, jnp.float32),
-            jnp.float32(pod.cpu_demand),
-            jnp.float32(pod.mem_demand),
-            self.cfg.w_d, self.cfg.w_e,
-            self.cfg.cpu_threshold, self.cfg.mem_threshold,
-        )
-
-    def _score_topk(self, pod, view):
-        """Sub-linear admission: one jit'd utilization prefilter over all
-        N nodes picks candidate_k candidates, then the expensive Eq. (4)
-        interference terms run on only those.
-
-        Always a fixed-size candidate set (infeasible candidates are
-        re-masked to -inf by ``_score_nodes``), so XLA compiles one
-        (k,)-shaped scorer regardless of fleet size.  Returns the best
-        *global* node index and a full-length score array with -inf
-        outside the candidate set.
-        """
-        from repro.cluster.fleet import topk_candidates
+        """(best global node or -1, full-length float32 scores, terms):
+        ``terms`` holds the candidate indices (None when every node is
+        scored) and the quantifier outputs the score used."""
         cfg = self.cfg
-        idx, _pre = topk_candidates(
-            jnp.asarray(view.cpu_cur, jnp.float32),
-            jnp.asarray(view.cpu_sum, jnp.float32),
-            jnp.asarray(view.mem_cur, jnp.float32),
-            jnp.asarray(view.mem_sum, jnp.float32),
-            jnp.float32(cfg.w_d * pod.cpu_demand),
-            jnp.float32(cfg.w_e * pod.mem_demand),
-            cfg.cpu_threshold, cfg.mem_threshold, cfg.candidate_k,
-        )
-        idx = np.asarray(idx)
-        best_local, score_k = self._score_exact(pod, view.take(idx))
-        score = np.full(view.num_nodes, -np.inf, np.float32)
-        score[idx] = np.asarray(score_k)
-        best = int(best_local)
-        return (-1 if best < 0 else int(idx[best])), score
+        idx = None
+        num_nodes = view.num_nodes
+        if num_nodes > cfg.candidate_k:
+            # sub-linear admission: one jit'd utilization prefilter over
+            # all N nodes picks candidate_k candidates, and the expensive
+            # Eq. (4) interference terms run on only those.  Always a
+            # fixed-size candidate set (infeasible candidates are re-masked
+            # to -inf by ``_score_nodes``), so XLA compiles one (k,)-shaped
+            # scorer regardless of fleet size
+            from repro.cluster.fleet import topk_candidates
+            with self.timers.phase("topk"):
+                idx, _pre = topk_candidates(
+                    jnp.asarray(view.cpu_cur, jnp.float32),
+                    jnp.asarray(view.cpu_sum, jnp.float32),
+                    jnp.asarray(view.mem_cur, jnp.float32),
+                    jnp.asarray(view.mem_sum, jnp.float32),
+                    jnp.float32(cfg.w_d * pod.cpu_demand),
+                    jnp.float32(cfg.w_e * pod.mem_demand),
+                    cfg.cpu_threshold, cfg.mem_threshold, cfg.candidate_k,
+                )
+                idx = np.asarray(idx)
+            view = view.take(idx)
+        with self.timers.phase("quantify"):
+            intf_nodes = self.q.intf_nodes(view.online_hists,
+                                           view.offline_hists)
+            intf_p = self.q.intf_pod(pod.qps, view.features)
+            fterm = self._forecast_term(view)
+        intf_h = intf_nodes if fterm is None else np.asarray(intf_nodes) + fterm
+        with self.timers.phase("score"):
+            best, score = _score_nodes(
+                jnp.asarray(view.cpu_cur, jnp.float32),
+                jnp.asarray(view.cpu_sum, jnp.float32),
+                jnp.asarray(view.mem_cur, jnp.float32),
+                jnp.asarray(view.mem_sum, jnp.float32),
+                jnp.asarray(intf_h, jnp.float32),
+                jnp.asarray(intf_p, jnp.float32),
+                jnp.float32(pod.cpu_demand),
+                jnp.float32(pod.mem_demand),
+                cfg.w_d, cfg.w_e, cfg.cpu_threshold, cfg.mem_threshold,
+            )
+            best, score = int(best), np.asarray(score)
+        terms = {"nodes": idx, "intf_nodes": np.asarray(intf_nodes),
+                 "intf_pod": np.asarray(intf_p),
+                 "forecast_term": (None if fterm is None
+                                   else np.asarray(fterm))}
+        if idx is None:
+            return best, score, terms
+        # the best *global* node, and a full-length score array with -inf
+        # outside the candidate set
+        full = np.full(num_nodes, -np.inf, np.float32)
+        full[idx] = score
+        return (-1 if best < 0 else int(idx[best])), full, terms
 
     def select_node(self, pod, view) -> int:
         """Algorithm 1.
@@ -154,55 +173,87 @@ class ICOScheduler:
              histograms, Table-III node features).
         Returns the selected node index or -1.
         """
-        best, score = self._score(pod, view)
-        if self.recorder:
-            self.recorder.emit(
-                self._admission_event(pod, view, np.asarray(score), int(best)))
-        return int(best)
+        best, score, terms = self._score(pod, view)
+        if self.decisions is not None or self.recorder:
+            entry = self._decision(pod, view, score, best, terms)
+            if self.decisions is not None:
+                self.decisions.append(entry)
+            if self.recorder:
+                self.recorder.emit(self._admission_event(entry))
+        return best
 
     def scores(self, pod, view) -> np.ndarray:
-        _, score = self._score(pod, view)
-        return np.asarray(score)
+        return self._score(pod, view)[1]
 
-    def _admission_event(self, pod, view, score: np.ndarray, best: int):
-        """Build the AdmissionDecision with the Eq. (4)-(6) term breakdown.
+    def _decision(self, pod, view, score, best: int, terms) -> dict:
+        """One decision-log entry: what this offer's choice used.
 
-        The breakdown is recomputed in numpy from the same view the jit'd
-        scorer consumed — cheap relative to the RF behind ``intf_pod``, and
-        it makes the trace self-contained: ``repro.obs.explain`` (and the
-        round-trip test) reproduce the recorded ``score`` from the stored
-        terms alone, without a cluster or a predictor in hand.
+        The pod's demand, the telemetry window the view covers
+        (``t``, ``window_ticks``), the Eqs. (5)-(6) utilization terms and
+        the feasible set (recomputed in float64 numpy from the view the
+        jit'd scorer consumed), the quantifier outputs (Eq. 1's
+        ``intf_nodes``, Eq. 3's ``intf_pod``) and ICO-F's ``forecast_term``
+        (None for ICO or a closed gate), the scores and the chosen node.
+        Every per-node array is full length; with the top-k prefilter the
+        quantifier outputs are NaN and the score -inf off the candidate
+        set ``nodes``.
         """
-        from repro.obs import AdmissionDecision
         cfg = self.cfg
         cpu_sum = np.asarray(view.cpu_sum, np.float64)
         mem_sum = np.asarray(view.mem_sum, np.float64)
         utiliz_cpu = (np.asarray(view.cpu_cur) + cfg.w_d * pod.cpu_demand) / cpu_sum
         utiliz_mem = (np.asarray(view.mem_cur) + cfg.w_e * pod.mem_demand) / mem_sum
-        feasible = ((utiliz_cpu <= cfg.cpu_threshold)
-                    & (utiliz_mem <= cfg.mem_threshold))
-        intf_h, intf_p = self._interference(pod, view)
-        breakdown = {
-            "utiliz_cpu": utiliz_cpu,
-            "utiliz_mem": utiliz_mem,
-            "intf_h": np.asarray(intf_h),
-            "intf_p": np.asarray(intf_p),
-            "feasible": feasible,
-            "score": score,
+        idx = terms["nodes"]
+
+        def full(a, fill):
+            if a is None or idx is None:
+                return a
+            out = np.full(view.num_nodes, fill, np.asarray(a).dtype)
+            out[idx] = a
+            return out
+
+        return {
+            "scheduler": self.name, "t": float(view.t),
+            "window_ticks": view.window_ticks,
+            "workload": pod.workload, "qps": float(pod.qps),
+            "online": bool(pod.is_online),
+            "cpu_demand": float(pod.cpu_demand),
+            "mem_demand": float(pod.mem_demand),
+            "nodes": None if idx is None else np.asarray(idx),
+            "utiliz_cpu": utiliz_cpu, "utiliz_mem": utiliz_mem,
+            "feasible": ((utiliz_cpu <= cfg.cpu_threshold)
+                         & (utiliz_mem <= cfg.mem_threshold)),
+            "intf_nodes": full(terms["intf_nodes"], np.nan),
+            "intf_pod": full(terms["intf_pod"], np.nan),
+            "forecast_term": full(terms["forecast_term"], np.nan),
+            "score": full(score, -np.inf),
+            "chosen": int(best),
         }
-        fterm = self._forecast_term(view)
-        if fterm is not None:
-            breakdown["forecast_term"] = np.asarray(fterm)
-            # intf_h above already absorbed the forecast addend (ICO-F's
-            # _interference hook); split it back out so the stored terms
-            # decompose the score without double-counting
-            breakdown["intf_h"] = breakdown["intf_h"] - breakdown["forecast_term"]
+
+    def _admission_event(self, entry: dict):
+        """The AdmissionDecision of one decision-log entry, with the
+        Eq. (4)-(6) term breakdown: ``repro.obs.explain`` (and the
+        round-trip test) reproduce the recorded ``score`` from the stored
+        terms alone, without a cluster or a predictor in hand."""
+        from repro.obs import AdmissionDecision
+        breakdown = {
+            "utiliz_cpu": entry["utiliz_cpu"],
+            "utiliz_mem": entry["utiliz_mem"],
+            "intf_h": entry["intf_nodes"],
+            "intf_p": entry["intf_pod"],
+            "feasible": entry["feasible"],
+            "score": entry["score"],
+        }
+        if entry["forecast_term"] is not None:
+            # stored apart from intf_h, so the terms decompose the score
+            # without double-counting
+            breakdown["forecast_term"] = entry["forecast_term"]
         # repro-lint: disable=R3 -- only caller (select_node) guards with `if self.recorder:`
         return AdmissionDecision(
-            scheduler=self.name, workload=pod.workload, qps=float(pod.qps),
-            online=bool(pod.is_online), cpu_demand=float(pod.cpu_demand),
-            mem_demand=float(pod.mem_demand), chosen=best,
-            breakdown=breakdown,
+            scheduler=entry["scheduler"], workload=entry["workload"],
+            qps=entry["qps"], online=entry["online"],
+            cpu_demand=entry["cpu_demand"], mem_demand=entry["mem_demand"],
+            chosen=entry["chosen"], breakdown=breakdown,
         )
 
 
@@ -231,13 +282,6 @@ class ICOFScheduler(ICOScheduler):
         if not w_f > 0.0:
             raise ValueError("w_f must be > 0 (use ICOScheduler to disable)")
         self.w_f = w_f
-
-    def _interference(self, pod, view):
-        intf_h, intf_p = super()._interference(pod, view)
-        fterm = self._forecast_term(view)
-        if fterm is not None:
-            intf_h = np.asarray(intf_h) + fterm
-        return intf_h, intf_p
 
     def _forecast_term(self, view):
         drift = view.forecast_drift()

@@ -749,3 +749,90 @@ def test_core_reexports_control_api():
     assert core.ControlLoopConfig is ControlLoopConfig
     with pytest.raises(AttributeError):
         core.definitely_not_a_symbol
+
+
+def _scatter_cleared(det_or_fc, nodes, slots):
+    """The eager scatter the masked clears replaced, as the reference."""
+    idx = (np.asarray(nodes), np.asarray(slots))
+    if isinstance(det_or_fc, StreamingDetector):
+        return [det_or_fc.slot_hist.at[idx].set(0.0),
+                det_or_fc.slot_prev.at[idx].set(0.0),
+                det_or_fc.slot_score.at[idx].set(0.0)]
+    return [det_or_fc.A.at[idx].set(0.0), det_or_fc.b.at[idx].set(0.0),
+            det_or_fc.err.at[idx].set(1.0), det_or_fc.count.at[idx].set(0)]
+
+
+def _track(det_or_fc):
+    if isinstance(det_or_fc, StreamingDetector):
+        return [det_or_fc.slot_hist, det_or_fc.slot_prev,
+                det_or_fc.slot_score]
+    return [det_or_fc.A, det_or_fc.b, det_or_fc.err, det_or_fc.count]
+
+
+@pytest.mark.parametrize("kind", ["detector", "forecaster"])
+def test_clear_slots_is_the_scatter_in_one_program(kind):
+    """clear_slots is one masked update of fixed (N, S) shape: bitwise the
+    scatter it replaced, for every number of cleared slots, and compiled
+    once for all of them (a scatter compiles once per index count)."""
+    from repro.control import detector as det_mod
+    from repro.control import forecast as fc_mod
+
+    rng = np.random.default_rng(7)
+    n, s = 5, S_ON + S_OFF
+    if kind == "detector":
+        obj, fn = StreamingDetector(n), det_mod._clear_slot_track
+        for _ in range(3):
+            obj.update(rng.poisson(3.0, (n, s, 200)).astype(np.float32))
+    else:
+        obj, fn = fc_mod.QPSForecaster(n, S_ON), fc_mod._clear_fits
+        s = S_ON
+        for t in range(4):
+            obj.update(40.0 * t, rng.uniform(100, 400, (n, s)),
+                       rng.random((n, s)) < 0.8)
+    sizes = []
+    for count in (1, 3, 7, 2 * n):
+        flat = rng.choice(n * s, count, replace=False)
+        nodes, slots = np.divmod(flat, s)
+        want = _scatter_cleared(obj, nodes, slots)
+        obj.clear_slots(nodes, slots)
+        for got, w in zip(_track(obj), want):
+            assert got.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(w))
+        sizes.append(fn._cache_size())
+    assert sizes[1:] == sizes[:1] * 3, sizes
+
+
+def test_decision_log_and_outcome_records_change_nothing():
+    """The scheduler's decision log and the loop's outcome records only
+    observe: a run with both on is bitwise the run with both off, and they
+    hold one entry per offer and one record per loop window."""
+    from repro.control import ForecastService
+    from repro.core.scheduler import ICOFScheduler
+
+    pods, gaps = bursty_trace(num_online=6, num_bursts=3, jobs_per_burst=3,
+                              seed=4)
+    runs = []
+    for on in (False, True):
+        q = _cheap_quantifier()
+        cfg = scheduler_loop_config("ICO-F", proactive=True)
+        svc = ForecastService(cfg.forecast, cfg.horizon)
+        loop = ControlLoop(q, cfg, forecast_service=svc)
+        sched = ICOFScheduler(q)
+        if on:
+            sched.decisions, loop.outcomes = [], []
+        plan: dict = {}
+        res = run_experiment(sched, pods, gaps, num_nodes=6, seed=9,
+                             control_loop=loop, forecast=svc,
+                             control_window=40, plan_out=plan)
+        runs.append((res, plan["log"], sched, loop))
+    (off, log_off, *_), (on, log_on, sched, loop) = runs
+    assert on == off and log_on == log_off
+    assert len(sched.decisions) == on.offers > 0
+    # a refusal is an offer that placed nothing (a full slot is another)
+    assert [d["chosen"] >= 0 for d in sched.decisions].count(False) <= \
+        on.offers_rejected
+    assert len(loop.outcomes) == loop.stats.steps
+    assert sum(len(o["hot"]) for o in loop.outcomes) == \
+        loop.stats.hotspots_flagged
+    assert {"admit.quantify", "admit.score", "admit.place", "rollout",
+            "detect"} <= set(on.phases)

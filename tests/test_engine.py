@@ -309,26 +309,34 @@ def test_rollout_span_holds_the_device_wait(monkeypatch, controlled):
 
 def test_rollout_phase_attribution():
     """The rollout phase must absorb the device compute it dispatches
-    (block_until_ready inside the timed region): the summed phase timers
-    cover most of the end-to-end wall, and rollout dominates them.
-    Without the block, the compute drains under untimed host code and
-    coverage collapses."""
+    (block_until_ready inside the timed region): the run's phase timers
+    (``ExperimentResult.phases``: the loop's and the scheduler's
+    ``admit.*``) cover most of the end-to-end wall, and rollout dominates
+    the loop's.  Without the block, the compute drains under untimed host
+    code and rollout's share collapses.
+
+    An untimed run on the same inputs compiles every phase's programs
+    first: which of them a worker has compiled before depends on the
+    test files it ran, and a phase that compiles inside the timed run
+    measures the compiler, not the phase."""
     from repro.cluster.experiment import _arrival_trace, run_experiment
     from repro.control import ControlLoop
     from repro.core import ICOScheduler, InterferenceQuantifier
 
     quant = InterferenceQuantifier(lambda x: np.asarray(x)[:, 0] * 0.1)
-    loop = ControlLoop(quant)
     sched = ICOScheduler(quant)
     pods, gaps = _arrival_trace(10, seed=3)
-    t0 = time.time()
     run_experiment(sched, pods, gaps, num_nodes=6, seed=5, fast=True,
-                   control_loop=loop, control_window=40)
+                   control_loop=ControlLoop(quant), control_window=40)
+    loop = ControlLoop(quant)
+    t0 = time.time()
+    res = run_experiment(sched, pods, gaps, num_nodes=6, seed=5, fast=True,
+                         control_loop=loop, control_window=40)
     wall = time.time() - t0
     totals = dict(loop.timers.totals)
-    covered = sum(totals.values())
+    covered = sum(res.phases.values())
     assert totals.get("rollout", 0.0) > 0.0
-    # generous slack: scheduling/retry bookkeeping and numpy conversions
-    # are legitimately untimed, but they are small next to the rollouts
-    assert covered >= 0.5 * wall, (totals, wall)
-    assert totals["rollout"] >= 0.5 * covered, (totals, wall)
+    # generous slack: retry bookkeeping and numpy conversions are
+    # legitimately untimed, but they are small next to the phases
+    assert covered >= 0.5 * wall, (res.phases, wall)
+    assert totals["rollout"] >= 0.5 * sum(totals.values()), (totals, wall)
