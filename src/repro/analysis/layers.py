@@ -90,11 +90,12 @@ LAYERING: tuple[LayerRule, ...] = (
 # --------------------------------------------------------------------------
 
 # Modules whose jax.jit / lax.scan / lax.switch roots seed the R1 call
-# closure.  These are the three modules the batched rollout core documented
-# as jit-pure in ROADMAP.md; add a module here when a new jit'd scoring
-# path (e.g. the planned multi-objective optimizer) is promoted to
-# load-bearing.
+# closure: the batched rollout core, the detector and forecaster folds, the
+# fused tick, the fleet prefilter and the replay's statistics program; add
+# a module here when a new jit'd scoring path (e.g. the planned
+# multi-objective optimizer) is promoted to load-bearing.
 JIT_ROOT_MODULES: tuple[str, ...] = (
+    "repro.cluster.experiment",
     "repro.cluster.fleet",
     "repro.cluster.state",
     "repro.control.detector",

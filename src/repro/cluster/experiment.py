@@ -512,6 +512,99 @@ def replay_inputs(plan: dict, sim_seeds, window_ticks: int = 40,
             "span": cpw * cstate.CHUNK}
 
 
+# np.percentile's default ("linear") method on float32 samples, as numpy 2
+# computes it: q = p / float32(100), h = (n - 1) * q, then a float32 lerp
+# between the order statistics at floor(h) and floor(h) + 1 (both clipped
+# to n - 1), from the lower one while the weight is below 0.5 and from the
+# upper one after
+_RT_Q = np.asarray((90, 99), np.float32) / np.float32(100)
+_WARMUP_TICKS = 30     # the RT pool and the utilization windows start here
+_INF_BITS = 0x7F800000  # +inf's bits: positive float32 values order as
+                        # their int32 bit patterns, all at or below these
+
+
+def _order_stats(bits, ranks):
+    """The ``ranks``-th smallest (0-based) sample of each row, exactly.
+
+    ``bits`` (B, ...) int32 holds the bit patterns of positive float32
+    samples, an excluded entry holding INT32_MAX; ``ranks`` (B, K) int32
+    lie below each row's sample count.  A bisection on the bit patterns:
+    each of its 31 passes halves every rank's interval, counting all K
+    ranks of a row in one pass over it (31 halvings close an interval
+    shorter than 2**31).  Returns (B, K) float32.
+    """
+    axes = tuple(range(1, bits.ndim))
+    row = (slice(None),) + (None,) * len(axes)
+
+    def halve(_, bounds):
+        lo, hi = bounds   # count(bits <= lo) <= rank < count(bits <= hi)
+        mid = lo + ((hi - lo) >> 1)
+        count = jnp.stack(
+            [jnp.sum(bits <= mid[:, k][row], axis=axes, dtype=jnp.int32)
+             for k in range(ranks.shape[1])], axis=1)
+        past = count > ranks
+        return jnp.where(past, lo, mid), jnp.where(past, mid, hi)
+
+    bounds = (jnp.zeros_like(ranks), jnp.full_like(ranks, _INF_BITS))
+    _, hi = jax.lax.fori_loop(0, 31, halve, bounds)
+    return jax.lax.bitcast_convert_type(hi, jnp.float32)
+
+
+def _pooled_rt(rt, valid):
+    """Per-seed count, mean and p90/p99 of the samples of ``rt`` (B, W,
+    span, N, S) that lie on a ``valid`` (W, span) tick and are above 0;
+    NaN statistics for a seed with none."""
+    keep = valid[None, :, :, None, None] & (rt > 0)
+    axes = (1, 2, 3, 4)
+    n = jnp.sum(keep, axis=axes, dtype=jnp.int32)
+    avg = jnp.sum(jnp.where(keep, rt, 0.0), axis=axes) / n
+    bits = jnp.where(keep, jax.lax.bitcast_convert_type(rt, jnp.int32),
+                     jnp.iinfo(jnp.int32).max)
+    h = (n - 1).astype(jnp.float32)[:, None] * _RT_Q         # (B, 2)
+    lo = jnp.clip(jnp.floor(h).astype(jnp.int32), 0, (n - 1)[:, None])
+    hi = jnp.minimum(lo + 1, (n - 1)[:, None])
+    x = _order_stats(bits, jnp.concatenate([lo, hi], axis=1))
+    a, b = x[:, :2], x[:, 2:]
+    t = h - jnp.floor(h)
+    diff = b - a
+    pct = jnp.where(t >= 0.5, b - diff * (1 - t), a + diff * t)
+    pct = jnp.where((n > 0)[:, None], pct, jnp.nan)
+    return {"count": n, "avg_rt": avg, "p90_rt": pct[:, 0],
+            "p99_rt": pct[:, 1]}
+
+
+@jax.jit
+def _replay_stats(rt, cpu_util, mem_util, hot, t_end, settle_ticks,
+                  num_windows):
+    """The per-seed answers of a replay, from ``batched_rollout``'s
+    outputs where they lie: ``_pooled_rt`` over the ticks in
+    [30, ``t_end``), the mean over the arrival-phase windows of the
+    cross-node CPU/memory utilization std (every window for a trace too
+    short to have one), and the windows below ``num_windows`` in which
+    the detector flagged a node.  Every reduction is per seed, so a
+    seed-sharded input stays sharded.  Returns a dict of (B,) arrays."""
+    padded_windows, span = rt.shape[1], rt.shape[2]
+    w_start = jnp.arange(padded_windows) * span
+    tick = w_start[:, None] + jnp.arange(span)[None, :]
+    pooled = _pooled_rt(rt, (tick >= _WARMUP_TICKS) & (tick < t_end))
+    wins = ((w_start >= _WARMUP_TICKS)
+            & (w_start + span <= t_end - settle_ticks))
+    wins = wins | ~jnp.any(wins)
+
+    def util_std(util):
+        std = jnp.std(100 * util, axis=-1)                    # (B, W)
+        return jnp.sum(jnp.where(wins, std, 0.0), axis=1) / jnp.sum(wins)
+
+    # padded windows simulate past t_end and could trip the detector; only
+    # the real prefix counts (it is bitwise the unbucketed scan's: the fold
+    # carry runs front-to-back)
+    real = jnp.arange(padded_windows) < num_windows
+    return dict(pooled, cpu_util_std=util_std(cpu_util),
+                mem_util_std=util_std(mem_util),
+                hot_windows=jnp.sum(jnp.any(hot, -1) & real, axis=1,
+                                    dtype=jnp.int32))
+
+
 def replay_plan_batched(
     plan: dict,
     sim_seeds=tuple(range(20)),
@@ -548,15 +641,18 @@ def replay_plan_batched(
     variable-length control windows), and the folded detector's
     hot-window count.  Warmup ticks
     (< 30) and any padding past ``t_end`` are excluded from the RT pool,
-    matching the reference driver's sampling span.
+    matching the reference driver's sampling span.  The statistics are
+    computed where the series lie (``_replay_stats``, one compiled program
+    of exact masked order statistics), so only the per-seed numbers come
+    back to the host.
 
     ``phases`` holds the call's seconds by phase, each also a span
     ``repro.replay.<phase>`` in a profile of the call: ``call`` (all of
     it), ``inputs`` (``replay_inputs``: ``plan``, the log filter and
     ``extract_plan``, then ``keys``, the compiled key stream), ``engine``
-    (``batched_rollout`` until its RT is on the host) and ``reduce`` (the
-    other transfers and the per-seed statistics).  ``wall_s`` is
-    ``phases["engine"]``.
+    (``batched_rollout`` until its outputs are ready on the device) and
+    ``reduce`` (``_replay_stats`` and the one transfer of its per-seed
+    numbers).  ``wall_s`` is ``phases["engine"]``.
     """
     from repro.cluster import state as cstate
 
@@ -567,49 +663,26 @@ def replay_plan_batched(
                                 timers=timers)
         t_end, settle_ticks = inp["t_end"], plan.get("settle_ticks", 40)
         num_windows, padded_windows = inp["num_windows"], inp["padded_windows"]
-        span = inp["span"]
 
         with timers.phase("engine"):
             _, outs = cstate.batched_rollout(
                 inp["state"], inp["profiles"], 0.0, inp["keys"],
                 inp["events"], fleet=inp["fleet"], devices=devices,
                 use_pallas=use_pallas)
-            rt = np.asarray(outs["rt"])  # (B, W, span, N, S_ON): syncs
+            outs = jax.block_until_ready(outs)
 
         with timers.phase("reduce"):
-            cpu = np.asarray(outs["cpu_util"])   # (B, W, N)
-            mem = np.asarray(outs["mem_util"])
-            hot = np.asarray(outs["hot"])        # (B, W, N)
-            tick_idx = (np.arange(padded_windows)[:, None] * span
-                        + np.arange(span)[None, :])  # (W, span) global tick
-            valid = (tick_idx >= 30) & (tick_idx < t_end)  # no warmup/padding
-            w_start = np.arange(padded_windows) * span
-            util_wins = ((w_start >= 30)
-                         & (w_start + span <= t_end - settle_ticks))
-            if not util_wins.any():
-                util_wins = np.ones(padded_windows, bool)  # short trace
-
-            seeds_out = []
-            for i, s in enumerate(sim_seeds):
-                r = rt[i][valid]
-                samples = r[r > 0]
-                if samples.size == 0:
-                    samples = np.full(1, np.nan)
-                seeds_out.append({
-                    "sim_seed": int(s),
-                    "avg_rt": float(samples.mean()),
-                    "p90_rt": float(np.percentile(samples, 90)),
-                    "p99_rt": float(np.percentile(samples, 99)),
-                    "cpu_util_std": float(
-                        (100 * cpu[i][util_wins]).std(axis=1).mean()),
-                    "mem_util_std": float(
-                        (100 * mem[i][util_wins]).std(axis=1).mean()),
-                    # padded windows simulate past t_end and could trip the
-                    # detector; only the real prefix counts (it is bitwise
-                    # the unbucketed scan's: the fold carry runs
-                    # front-to-back)
-                    "hot_windows": int(hot[i][:num_windows].any(-1).sum()),
-                })
+            stats = jax.device_get(_replay_stats(
+                outs["rt"], outs["cpu_util"], outs["mem_util"], outs["hot"],
+                np.int32(t_end), np.int32(settle_ticks),
+                np.int32(num_windows)))
+            seeds_out = [{
+                "sim_seed": int(s),
+                **{k: float(stats[k][i]) for k in (
+                    "avg_rt", "p90_rt", "p99_rt", "cpu_util_std",
+                    "mem_util_std")},
+                "hot_windows": int(stats["hot_windows"][i]),
+            } for i, s in enumerate(sim_seeds)]
     phases = timers.pop_window()
     return {"seeds": seeds_out, "wall_s": phases["engine"], "phases": phases,
             "num_windows": num_windows, "padded_windows": padded_windows}

@@ -115,7 +115,7 @@ def test_r1_silent_on_pure_code_and_out_of_scope_modules():
     _, found = rules_hit(pure, "R1")
     assert found == []
     # same host calls, but not a jit-root module: out of R1's scope
-    _, found = rules_hit(sf("repro.cluster.experiment", R1_BAD), "R1")
+    _, found = rules_hit(sf("repro.cluster.simulator", R1_BAD), "R1")
     assert found == []
 
 
@@ -136,6 +136,25 @@ def test_r1_covers_fleet_prefilter_roots():
     """)
     _, found = rules_hit(fixture, "R1")
     assert len(found) == 1 and "time.time" in found[0].message
+
+
+def test_r1_covers_replay_stats_roots():
+    """repro.cluster.experiment is a jit-root module: a host pull reachable
+    from the replay's jit'd statistics program must fire R1."""
+    assert "repro.cluster.experiment" in layers.JIT_ROOT_MODULES
+    fixture = sf("repro.cluster.experiment", """\
+        import jax
+        import jax.numpy as jnp
+
+        def _pooled(rt):
+            return float(rt.sum())
+
+        @jax.jit
+        def _replay_stats(rt):
+            return jnp.asarray(_pooled(rt))
+    """)
+    _, found = rules_hit(fixture, "R1")
+    assert len(found) == 1 and "float()" in found[0].message
 
 
 def test_r1_covers_rollout_tick_roots():
