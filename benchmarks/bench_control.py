@@ -26,10 +26,11 @@ Two grids:
   counts, and the forecaster's one-step calibration error.
 
 * **Batched axis** (always) — the acceptance demo for the vmapped rollout
-  core: one 3-day ICO trace is run through the 2-seed per-chunk Python
-  loop, then its placement/action plans, one without mitigation and one
-  with a reactive ControlLoop, are replayed over >= 20 sim seeds in ONE
-  ``state.batched_rollout`` call each.  Reported: both wall-clocks,
+  core: one 3-day ICO trace is run under 2 sim seeds by ``run_experiment``,
+  whose Python loop advances ``Cluster.rollout`` window by window (the
+  ``python_loop`` row), then its placement/action plans, one without
+  mitigation and one with a reactive ControlLoop, are replayed over >= 20
+  sim seeds in ONE ``state.batched_rollout`` call each.  Reported: both wall-clocks,
   per-seed cost of each path, p99 mean +/- std per mode across seeds, the
   per-seed mitigation win/loss record, and a parity check (the replay
   entry whose seed equals the reference run's must reproduce its p99).
@@ -201,12 +202,13 @@ def _profile_grid(predictor, seeds, out, json_doc):
 
 
 def _batched_axis(out, json_doc, sim_seeds=BATCHED_SIM_SEEDS):
-    """ISSUE-7 acceptance axis: >= 20 vmapped seeds vs the 2-seed Python
-    loop, on one 3-day ICO trace, with and without reactive mitigation.
+    """Acceptance axis: >= 20 vmapped seeds vs 2 per-window Python-driven
+    runs, on one 3-day ICO trace, with and without reactive mitigation.
 
     Every run here uses the same lightweight linear predictor (not the
     trained RF), so the rows time the rollout core, not scheduler quality.
-    The baseline is the 2-seed protocol on the per-chunk Python loop.
+    The baseline is the 2-seed protocol of ``run_experiment``, one
+    ``Cluster.rollout`` dispatch per window driven from Python.
     """
     from repro.core import ICOScheduler
 
@@ -216,7 +218,7 @@ def _batched_axis(out, json_doc, sim_seeds=BATCHED_SIM_SEEDS):
     ref_seed = 11
     quantify = InterferenceQuantifier(lambda x: np.asarray(x)[:, 0] * 0.1)
 
-    # the 2-seed protocol on the per-chunk Python loop (fast=False)
+    # the 2-seed protocol, driven from Python window by window
     plan_off: dict = {}
     baseline = []
     t0 = time.time()
@@ -224,11 +226,11 @@ def _batched_axis(out, json_doc, sim_seeds=BATCHED_SIM_SEEDS):
         sched = ICOScheduler(quantify)
         baseline.append(run_experiment(
             sched, pods, gaps, num_nodes=12, seed=sim_seed,
-            control_window=CONTROL_WINDOW, fast=False,
+            control_window=CONTROL_WINDOW,
             plan_out=plan_off if i == 0 else None))
     python_wall = time.time() - t0
 
-    # reactive reference on the scanned fast path; its plan carries the
+    # reactive reference run; its plan carries the
     # control loop's migrations/resizes, so the replay exercises the full
     # event vocabulary
     sched = ICOScheduler(quantify)
@@ -305,7 +307,7 @@ def _batched_axis(out, json_doc, sim_seeds=BATCHED_SIM_SEEDS):
             "seeds": [ref_seed, ref_seed + 1],
             "wall_s": python_wall,
             "p99": [r.p99_rt for r in baseline],
-            "core": "per-chunk Python loop",
+            "core": "per-window Python loop",
         },
         "vmap_wall_off_s": batch_off["wall_s"],
         "vmap_wall_on_s": batch_on["wall_s"],

@@ -16,10 +16,7 @@ phases (rollout / detect / forecast / plan / verify) from the loop's
 ``PhaseTimers`` — the baseline ROADMAP item 2's 5k-node latency gate
 measures against.  Phase means include JAX dispatch; the first window
 carries jit compilation, which is why the split is reported over ~30
-windows rather than one.  The ``rollout.python`` / ``rollout.scanned``
-rows time one telemetry window under the legacy per-chunk Python loop vs
-the lax.scan core side by side (the rollout phase itself now runs on the
-scanned core, matching what ``run_experiment``'s fast path dispatches).
+windows rather than one.
 """
 from __future__ import annotations
 
@@ -135,35 +132,11 @@ def _phase_timers(out, windows: int = 30, window_ticks: int = 40):
         if node >= 0:
             cluster.place(pod, node)
         cluster.rollout(10)
-    # before/after rows for the scanned rollout core: the same window
-    # advanced by the legacy per-chunk Python loop vs one lax.scan over the
-    # chunk keys.  One warm call each first, so the rows time steady-state
-    # dispatch, not jit compilation.
-    reps = 10
-    cluster.rollout(window_ticks)
-    t0 = time.time()
-    for _ in range(reps):
-        cluster.rollout(window_ticks)
-    py_ms = (time.time() - t0) / reps * 1e3
-    cluster.rollout_scan(window_ticks)
-    t0 = time.time()
-    for _ in range(reps):
-        cluster.rollout_scan(window_ticks)
-    scan_ms = (time.time() - t0) / reps * 1e3
-    out.append((
-        "scheduler_latency.rollout.python", py_ms * 1e3,
-        f"reps={reps};mean_ms={py_ms:.2f}",
-    ))
-    out.append((
-        "scheduler_latency.rollout.scanned", scan_ms * 1e3,
-        f"reps={reps};mean_ms={scan_ms:.2f};"
-        f"speedup={py_ms / max(scan_ms, 1e-9):.1f}x",
-    ))
 
     loop = ControlLoop(q, scheduler_loop_config("ICO", proactive=True))
     for _ in range(windows):
         with loop.timers.phase("rollout"):
-            cluster.rollout_scan(window_ticks)
+            cluster.rollout(window_ticks)
         loop.step(cluster)
     for phase, s in sorted(loop.timers.summary().items()):
         out.append((

@@ -41,7 +41,7 @@ def main():
     ico.recorder = rec
 
     cluster = Cluster(num_nodes=6, seed=3)
-    cluster.rollout_scan(30)
+    cluster.rollout(30)
     rec.begin_window(cluster.t)
 
     print("== submitting a mixed train+serve pod stream through ICO ==")
@@ -66,7 +66,7 @@ def main():
         ok = node >= 0 and cluster.place(pod, node)
         rec.resolve_admission(uid=pod.uid if ok else -1, placed=ok)
         placements.append((kind, node if ok else -1))
-        cluster.rollout_scan(10)
+        cluster.rollout(10)
         rec.begin_window(cluster.t)
         print(f"   pod {i:2d} {kind:18s} -> node {node if ok else 'REJECTED'}")
 
@@ -112,7 +112,7 @@ def selftest() -> None:
     rec = TraceRecorder()
     sched.recorder = rec
     cluster = Cluster(num_nodes=2, seed=0)
-    cluster.rollout_scan(3)
+    cluster.rollout(3)
     rec.begin_window(cluster.t)
     prof = ONLINE_PROFILES["web_search"]
     pod = Pod("web_search", 200.0, True)

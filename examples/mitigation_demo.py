@@ -69,7 +69,7 @@ def main() -> None:
     loop = ControlLoop(InterferenceQuantifier(lambda X: X[:, 21]),
                        recorder=rec)
     cluster = Cluster(num_nodes=6, seed=42)
-    cluster.rollout_scan(20)
+    cluster.rollout(20)
     rec.begin_window(cluster.t)
 
     print("== placing online fleet via ICO ==")
@@ -82,9 +82,9 @@ def main() -> None:
             raise RuntimeError(f"ICO could not place {name}")
         rec.resolve_admission(uid=pod.uid, placed=True)
         print(f"  {name:16s} qps={qps:5.0f} -> node {node}")
-        cluster.rollout_scan(10)
+        cluster.rollout(10)
 
-    cluster.rollout_scan(30)
+    cluster.rollout(30)
     print("node delays:", np.round(cluster.last["delay"], 1))
 
     print("\n== offline burst lands on node 0 ==")
@@ -95,12 +95,12 @@ def main() -> None:
         job.mem_demand = 12.0 * prof.mem_per_core
         if not cluster.place(job, 0):
             raise RuntimeError("node 0 has no free offline slot")
-    cluster.rollout_scan(10)
+    cluster.rollout(10)
     print("node delays:", np.round(cluster.last["delay"], 1))
 
     print("\n== control loop: detect -> attribute -> rank -> act -> verify ==")
     for step in range(8):
-        cluster.rollout_scan(10)
+        cluster.rollout(10)
         rec.begin_window(cluster.t)
         applied = loop.step(cluster)
         delays = np.round(cluster.last["delay"], 1)
@@ -144,7 +144,7 @@ def proactive_main() -> None:
     loop = ControlLoop(InterferenceQuantifier(lambda X: X[:, 21]),
                        ControlLoopConfig(proactive=True), recorder=rec)
     cluster = Cluster(num_nodes=6, seed=42)
-    cluster.rollout_scan(20)
+    cluster.rollout(20)
     rec.begin_window(cluster.t)
 
     print("== placing online fleet via ICO ==")
@@ -156,7 +156,7 @@ def proactive_main() -> None:
         if node < 0 or not cluster.place(pod, node):
             raise RuntimeError(f"ICO could not place {name}")
         rec.resolve_admission(uid=pod.uid, placed=True)
-        cluster.rollout_scan(10)
+        cluster.rollout(10)
 
     prof = OFFLINE_PROFILES["graph_analytics"]
     window, num_windows = 40, 95  # ~1.3 diurnal periods of telemetry
@@ -169,7 +169,7 @@ def proactive_main() -> None:
             job.cpu_demand = 10.0
             job.mem_demand = 10.0 * prof.mem_per_core
             cluster.place(job, 0)
-        cluster.rollout_scan(window)
+        cluster.rollout(window)
         rec.begin_window(cluster.t)
         applied = loop.step(cluster)
         if not armed and loop.forecaster is not None:
@@ -219,13 +219,13 @@ def selftest() -> None:
     loop = ControlLoop(InterferenceQuantifier(lambda X: X[:, 21]),
                        recorder=rec)
     cluster = Cluster(num_nodes=2, seed=0)
-    cluster.rollout_scan(3)
+    cluster.rollout(3)
     rec.begin_window(cluster.t)
     pod = make_online("web_search", 300)
     node = scheduler.select_node(pod, cluster.view())
     assert node >= 0 and cluster.place(pod, node), "admission failed"
     rec.resolve_admission(uid=pod.uid, placed=True)
-    cluster.rollout_scan(3)
+    cluster.rollout(3)
     rec.begin_window(cluster.t)
     loop.step(cluster)
     assert Trace(rec.events).query("admission", placed=True)

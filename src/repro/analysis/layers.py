@@ -68,12 +68,11 @@ LAYERING: tuple[LayerRule, ...] = (
               why="fleet is leaf data (classes, topology, prefilter); it "
                   "must stay importable without the control stack"),
     # kernels are leaf accelerator code: they consume packed arrays and
-    # constants from repro.core, never views/policies — the rollout engine
-    # imports THEM (function-level), not the other way around.
+    # constants from repro.core, never views/policies.
     LayerRule("repro.kernels", "repro.control", transitive=True,
               why="kernels are leaf accelerator code; depending on the "
                   "control stack would drag host policy into every "
-                  "fused-tick trace"),
+                  "kernel trace"),
     # the linter itself: stdlib-only, lintable-while-broken.
     LayerRule("repro.analysis", "repro", allow=("repro.analysis",),
               why="the linter must be able to lint a tree whose runtime "
@@ -91,7 +90,7 @@ LAYERING: tuple[LayerRule, ...] = (
 
 # Modules whose jax.jit / lax.scan / lax.switch roots seed the R1 call
 # closure: the batched rollout core, the detector and forecaster folds, the
-# fused tick, the fleet prefilter and the replay's statistics program; add
+# fleet prefilter and the replay's statistics program; add
 # a module here when a new jit'd scoring path (e.g. the planned
 # multi-objective optimizer) is promoted to load-bearing.
 JIT_ROOT_MODULES: tuple[str, ...] = (
@@ -100,7 +99,6 @@ JIT_ROOT_MODULES: tuple[str, ...] = (
     "repro.cluster.state",
     "repro.control.detector",
     "repro.control.forecast",
-    "repro.kernels.rollout_tick",
 )
 
 # Dotted call prefixes that are host-side by definition: calling any of

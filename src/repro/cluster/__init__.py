@@ -18,7 +18,7 @@ from repro.cluster.fleet import (
     make_fleet,
 )
 from repro.cluster.simulator import Cluster, ClusterState, NodeSpec, S_ON, S_OFF
-from repro.cluster.state import FleetParams, batched_rollout, scan_windows
+from repro.cluster.state import FleetParams, batched_rollout
 from repro.cluster.view import ClusterView
 from repro.cluster.workloads import (
     Pod,
@@ -40,7 +40,6 @@ __all__ = [
     "NodeSpec",
     "make_fleet",
     "batched_rollout",
-    "scan_windows",
     "S_ON",
     "S_OFF",
     "Pod",

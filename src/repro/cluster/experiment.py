@@ -175,7 +175,6 @@ def run_experiment(
     retry_limit: int = 8,
     retry_attempts: int = 3,
     recorder=None,
-    fast: bool | None = None,
     plan_out: dict | None = None,
 ) -> ExperimentResult:
     """Replay one arrival trace under a scheduler.
@@ -221,15 +220,8 @@ def run_experiment(
         (hotspots, actions, trust-gate flips — unless they already carry
         their own recorder), and the driver itself (window boundaries,
         retry-queue transitions, per-window phase timings).  Tracing only
-        observes; the simulated decisions are identical with or without it.
-    fast: rollout path selection.  ``True`` drives every window through
-        ``Cluster.rollout_scan`` (all chunks in one jit dispatch), ``False``
-        through the legacy per-chunk Python loop.  Default (``None``): fast
-        unless a recorder is attached — recorder runs are the reference
-        artifacts (per-window PhaseTimings, regression forensics), so they
-        stay on the historical Python path whose per-chunk dispatch the
-        recorded timings describe.  Both paths consume the identical key
-        stream and merge, so results match bit-for-bit either way.
+        observes, between windows; every window runs ``Cluster.rollout``
+        with or without it, so the simulated decisions are identical.
     plan_out: optional dict, filled on exit with the run's replayable plan
         (the cluster's mutation log + trace geometry) for
         ``replay_plan_batched`` — the vmapped many-seed re-evaluation of
@@ -269,10 +261,8 @@ def run_experiment(
                   s.predicted_reduction, s.realized_reduction)
     cluster = Cluster(num_nodes=num_nodes, seed=seed, fleet=fleet)
     num_nodes = cluster.n  # fleet overrides the scalar argument
-    use_scan = fast if fast is not None else (recorder is None)
-    roll = cluster.rollout_scan if use_scan else cluster.rollout
     with timers.phase("rollout"):
-        jax.block_until_ready((roll(30), cluster.state.cpu_sum))
+        jax.block_until_ready((cluster.rollout(30), cluster.state.cpu_sum))
     if recorder is not None:
         recorder.begin_window(cluster.t)
     rt_all: list[np.ndarray] = []
@@ -355,7 +345,8 @@ def run_experiment(
                 # drains under whatever runs next (the untimed RT-sample
                 # conversion, or a later phase) and "rollout" only measures
                 # trace/dispatch overhead
-                jax.block_until_ready((roll(w), cluster.state.cpu_sum))
+                jax.block_until_ready((cluster.rollout(w),
+                                       cluster.state.cpu_sum))
             rt_all.append(cluster.online_rt_samples())
             if record_util:
                 cpu_series.append(cluster.last["cpu_util"])
@@ -611,7 +602,6 @@ def replay_plan_batched(
     window_ticks: int = 40,
     bucket: bool = True,
     devices: int = None,
-    use_pallas: bool = False,
 ) -> dict:
     """Re-evaluate one run's placement/action plan under many sim seeds.
 
@@ -632,7 +622,7 @@ def replay_plan_batched(
     past ``t_end`` and are already excluded by the RT/util masks, so the
     numbers are bitwise those of the unbucketed replay.  ``devices=N``
     shards the seed axis across host devices (``state.batched_rollout``'s
-    shard_map path) and ``use_pallas=True`` runs the fused tick kernel.
+    shard_map path).
 
     Returns ``{"seeds": [...], "wall_s": float, "phases": dict,
     "num_windows": int, "padded_windows": int}``; each per-seed entry
@@ -667,8 +657,7 @@ def replay_plan_batched(
         with timers.phase("engine"):
             _, outs = cstate.batched_rollout(
                 inp["state"], inp["profiles"], 0.0, inp["keys"],
-                inp["events"], fleet=inp["fleet"], devices=devices,
-                use_pallas=use_pallas)
+                inp["events"], fleet=inp["fleet"], devices=devices)
             outs = jax.block_until_ready(outs)
 
         with timers.phase("reduce"):
@@ -686,27 +675,6 @@ def replay_plan_batched(
     phases = timers.pop_window()
     return {"seeds": seeds_out, "wall_s": phases["engine"], "phases": phases,
             "num_windows": num_windows, "padded_windows": padded_windows}
-
-
-def run_experiment_batched(
-    scheduler,
-    pods: list[Pod],
-    gaps: list[int],
-    num_nodes: int = 12,
-    seed: int = 7,
-    sim_seeds=tuple(range(20)),
-    window_ticks: int = 40,
-    **run_kwargs,
-) -> tuple[ExperimentResult, dict]:
-    """One reference ``run_experiment`` (scanned fast path) + a vmapped
-    replay of its plan across ``sim_seeds``.  Returns (reference_result,
-    ``replay_plan_batched`` output)."""
-    plan: dict = {}
-    ref = run_experiment(scheduler, pods, gaps, num_nodes=num_nodes,
-                         seed=seed, plan_out=plan, **run_kwargs)
-    batch = replay_plan_batched(plan, sim_seeds=sim_seeds,
-                                window_ticks=window_ticks)
-    return ref, batch
 
 
 def compare_schedulers(

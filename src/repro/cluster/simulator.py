@@ -21,18 +21,13 @@ stateful shell the drivers talk to — it owns the host-side bookkeeping
 (pod-uid map, numpy RNG for phases/bursts, the JAX key), delegates every
 mutation to the pure transforms, and **logs each mutation as a replayable
 event** so an entire run's placement/mitigation schedule can be replayed
-inside the scanned core (``state.scan_windows`` / ``state.batched_rollout``)
-under fresh simulation seeds.
+inside the scanned core (``state.batched_rollout``) under fresh simulation
+seeds.
 
-Two rollout paths, identical semantics:
-
-  * ``rollout(n)``   — the legacy chunk loop: one jit dispatch per 10-tick
-    chunk, summaries merged host-side.  Kept as the reference ("Python")
-    path.
-  * ``rollout_scan(n)`` — all chunks scanned in ONE jit dispatch
-    (``state.rollout_chunks``) with the identical per-chunk key stream and
-    the identical host-side merge, so results match the legacy path
-    bit-for-bit while eliminating the per-chunk Python dispatch overhead.
+``rollout(n)`` advances the cluster: every 10-tick chunk of the window is
+scanned in ONE jit dispatch (``state.rollout_chunks``), each chunk keyed by
+an iterative split of the cluster's key (``state.chunk_key_stream``), and
+the per-chunk summaries are merged host-side (``state.merge_summaries``).
 """
 from __future__ import annotations
 
@@ -76,10 +71,6 @@ class NodeSpec:
     cluster's spec would silently retune every later cluster."""
     cores: float = 32.0
     mem_gb: float = 64.0
-
-
-# legacy alias: the jit'd window kernel used to be defined here
-_rollout = cstate.rollout_window
 
 
 class Cluster:
@@ -314,29 +305,13 @@ class Cluster:
     # ---------------- simulation ----------------
 
     def rollout(self, num_ticks: int) -> dict:
-        """Advance ~num_ticks ticks (rounded up to CHUNK multiples) through
-        the legacy chunk loop: one jit dispatch per chunk."""
-        chunks = max(1, -(-num_ticks // self.CHUNK))
-        parts = []
-        for _ in range(chunks):
-            self.key, k = jax.random.split(self.key)
-            self.state, summary = cstate.rollout_window(
-                self.state, self.profiles, self.fleet_params,
-                jnp.float32(self.t), k, self.CHUNK
-            )
-            self.t += self.CHUNK
-            parts.append(summary)
-        self.last = jax.tree.map(np.asarray, cstate.merge_summaries(parts))
-        self.reconcile()
-        return self.last
+        """Advance ~num_ticks ticks (rounded up to CHUNK multiples) in one
+        jit dispatch; returns the window's merged telemetry (``self.last``).
 
-    def rollout_scan(self, num_ticks: int) -> dict:
-        """``rollout`` with every chunk scanned in ONE jit dispatch.
-
-        Consumes the identical per-chunk key stream (iterative splits of
-        ``self.key``) and merges the stacked per-chunk summaries with the
-        identical host-side reduction, so placements, telemetry, and the
-        advanced key match the legacy chunk loop bit-for-bit.
+        Chunk ``i`` is keyed by the ``i``-th iterative split of
+        ``self.key``, and the stacked per-chunk summaries are merged
+        host-side, so the result is bitwise an eager loop of per-chunk
+        ``_window_core`` calls (the oracle in ``tests/test_state.py``).
         """
         chunks = max(1, -(-num_ticks // self.CHUNK))
         self.key, ks = cstate.chunk_key_stream(self.key, chunks)
@@ -350,6 +325,9 @@ class Cluster:
         self.last = cstate.merge_summaries(parts)
         self.reconcile()
         return self.last
+
+    # chipbench's live cell calls the rollout by this name
+    rollout_scan = rollout
 
     # ---------------- Data Collection Module ----------------
 

@@ -29,15 +29,17 @@ array-first rebuild:
   over op codes, so an entire experiment's placement/mitigation schedule
   becomes data a jit'd rollout can consume.
 
-* Scanned rollout — ``rollout_chunks`` scans whole multi-chunk windows in
-  one dispatch (bit-compatible with the legacy chunk loop: identical
-  per-chunk key stream, identical host-side summary merge), and
-  ``scan_windows`` scans telemetry *windows* with the detector's node-track
-  CUSUM and the forecaster's harmonic moments folded into the carry.
+* Scanned rollout — ``rollout_chunks`` scans a whole multi-chunk window in
+  one dispatch (the live ``Cluster.rollout``: each chunk keyed from
+  ``chunk_key_stream``, summaries merged host-side by
+  ``merge_summaries``), and ``_scan_windows_impl`` scans telemetry
+  *windows* with the detector's node-track CUSUM and the forecaster's
+  harmonic moments folded into the carry.  Both run the one tick body,
+  ``_tick``, through ``_window_core``.
 
-* ``batched_rollout`` — vmap of ``scan_windows`` over a leading seed axis:
-  one call evaluates 20+ simulation seeds of a 3-day trace against a fixed
-  placement/action plan (common-random-placements replay).  With
+* ``batched_rollout`` — vmap of ``_scan_windows_impl`` over a leading seed
+  axis: one call evaluates 20+ simulation seeds of a 3-day trace against a
+  fixed placement/action plan (common-random-placements replay).  With
   ``devices=N`` the seed axis is additionally **sharded across host
   devices** via ``shard_map`` (the ``launch/mesh.py`` +
   ``XLA_FLAGS=--xla_force_host_platform_device_count`` idiom from the
@@ -48,23 +50,19 @@ array-first rebuild:
 
 Compile-once engine properties:
 
-* The ``ClusterState`` / detector / forecaster scan carries are **donated**
-  at the ``rollout_chunks`` / ``scan_windows`` / stacked ``batched_rollout``
-  entry points (``donate_argnums``), so XLA reuses the input buffers for
-  the output state instead of holding both live across the dispatch — at
-  5k nodes that halves the peak footprint of the mutable state.  Callers
-  must treat the passed-in state as consumed (the ``Cluster`` shell always
-  reassigns ``self.state`` from the result).
+* The ``ClusterState`` scan carry is **donated** at the ``rollout_chunks``
+  and stacked ``batched_rollout`` entry points (``donate_argnums``), so
+  XLA reuses the input buffers for the output state instead of holding
+  both live across the dispatch — at 5k nodes that halves the peak
+  footprint of the mutable state.  Callers must treat the passed-in
+  state as consumed (the ``Cluster`` shell always reassigns
+  ``self.state`` from the result).
 * ``extract_plan(..., bucket=True)`` pads the event arrays to power-of-two
   **size-class buckets** (events-per-chunk and window count), so every
   same-class plan of a scenario suite or optimizer candidate sweep replays
   through ONE compiled executable instead of recompiling per plan.  NOOP
   padding events are identity transforms and padded windows extend the key
   stream prefix-stably, so the un-padded prefix is bitwise unchanged.
-* ``use_pallas=True`` swaps the tick's sampling+binning hot loop for the
-  fused ``repro.kernels.rollout_tick`` kernel (Erlang(2) draw + delay
-  curve + node-histogram accumulation in one pass); the jnp path stays the
-  default-and-reference.
 
 The per-window outputs are deliberately "lite" (RT series, window-mean
 utilization, folded hotspot flags) — stacking per-tick slot histograms
@@ -673,16 +671,14 @@ def _window_core(state: ClusterState, profiles, fleet, t0, key,
     return state, summary
 
 
-rollout_window = jax.jit(_window_core, static_argnames=("num_ticks",))
-
 
 def _rollout_chunks_impl(state: ClusterState, profiles, fleet, t0, keys):
     """Scan CHUNK-tick chunks under one dispatch; ``keys`` is (chunks, 2).
 
-    Returns (final_state, stacked per-chunk summaries).  Each chunk runs the
-    exact legacy computation with its own key, so merging the stacked
-    summaries host-side (``merge_summaries``) reproduces the chunk-loop
-    path bit-for-bit.
+    Returns (final_state, stacked per-chunk summaries).  Each chunk is one
+    ``_window_core`` call with its own key, so merging the stacked
+    summaries host-side (``merge_summaries``) reproduces an eager loop of
+    per-chunk ``_window_core`` calls bit-for-bit.
 
     The incoming ``state`` is donated: XLA writes the final state back into
     the input buffers, so the dispatch never holds two full copies of the
@@ -704,7 +700,7 @@ rollout_chunks = jax.jit(_rollout_chunks_impl, donate_argnums=(0,))
 
 @partial(jax.jit, static_argnames=("num_chunks",))
 def chunk_key_stream(key, num_chunks: int):
-    """Replicate ``Cluster.rollout``'s iterative per-chunk key splits.
+    """The per-chunk keys of ``Cluster.rollout``: iterative splits of its key.
 
     Returns (advanced_key, (num_chunks, 2) stacked chunk keys): a
     ``lax.scan`` of ``key, k = jax.random.split(key)``, one compiled
@@ -741,7 +737,7 @@ def replay_key_stream(keys, num_windows: int, cpw: int):
 
 
 def merge_summaries(parts: list[dict]):
-    """The legacy host-side chunk merge: histograms sum, series concatenate,
+    """The host-side chunk merge: histograms sum, series concatenate,
     everything else is the mean of per-chunk means.  Works on np or jnp
     leaves (IEEE adds in the same order, so both give identical bits)."""
     if len(parts) == 1:
@@ -757,146 +753,6 @@ def merge_summaries(parts: list[dict]):
         else:
             merged[k] = sum(vals[1:], vals[0]) / len(vals)
     return merged
-
-
-# --------------------------------------------------------------------------
-# fused-kernel tick variant (lite outputs only)
-# --------------------------------------------------------------------------
-
-
-def _tick_pallas(st: ClusterState, profiles, fleet: FleetParams, t, key):
-    """``_tick`` with the sampling+binning hot loop fused into one Pallas
-    kernel (``repro.kernels.rollout_tick``): Erlang(2) draw, per-node delay
-    curve and node-histogram accumulation happen in a single VMEM pass.
-
-    Draws the EXACT same random stream as ``_tick`` (same key folds, same
-    shapes), so the kernel consumes bit-identical uniforms/normals and the
-    fused path stays numerically interchangeable with the jnp reference.
-    Only the lite outputs are produced — the scan-over-windows path is the
-    sole consumer, and it never looks at per-slot histograms or hw/perf
-    telemetry.
-    """
-    from repro.kernels.rollout_tick import fused_tick
-
-    k_qps, k_lat, k_rt, _k_hw = jax.random.split(key, 4)
-
-    on_active = st.on_active
-    on_type = st.on_type
-    on_qps_mean = st.on_qps_mean
-    on_phase = st.on_phase
-
-    qps_noise = 1.0 + 0.06 * jax.random.normal(k_qps, on_qps_mean.shape)
-    qps_t = on_qps_mean * _season(t, on_phase) * qps_noise
-    qps_t = jnp.where(on_active, jnp.maximum(qps_t, 0.0), 0.0)
-
-    cpu_on = jnp.where(
-        on_active,
-        profiles["cpu_per_qps"][on_type] * qps_t + profiles["cpu_base"][on_type],
-        0.0,
-    )
-    thr_on = jnp.where(on_active, profiles["threads_per_qps"][on_type] * qps_t, 0.0)
-    mem_on = jnp.where(
-        on_active,
-        profiles["mem_per_qps"][on_type] * qps_t + profiles["mem_base"][on_type],
-        0.0,
-    )
-
-    off_active = st.off_active
-    cpu_off = jnp.where(off_active, st.off_cores, 0.0)
-    thr_off = jnp.where(off_active, st.off_threads, 0.0)
-    mem_off = jnp.where(off_active, st.off_mem, 0.0)
-    burst_off = jnp.where(off_active, st.off_burst, 0.0)
-
-    cores = st.cpu_sum
-    total_cpu = cpu_on.sum(-1) + cpu_off.sum(-1) + OS_BASE_CORES
-    pressure_cpu = cpu_on.sum(-1) + (cpu_off * burst_off).sum(-1) + OS_BASE_CORES
-    rho_p = pressure_cpu / cores
-    threads_total = thr_on.sum(-1) + thr_off.sum(-1) + 2.0
-
-    # the same folds _tick performs: 99 -> delay jitter, (0|1, 0) -> pod
-    # jitter, (0|1, 1) -> the Erlang uniforms
-    e_delay = jax.random.normal(jax.random.fold_in(k_lat, 99), rho_p.shape)
-    k_on = jax.random.fold_in(k_lat, 0)
-    k_off = jax.random.fold_in(k_lat, 1)
-    tiny = jnp.finfo(jnp.float32).tiny
-    jit_on = 1.0 + 0.18 * jax.random.normal(
-        jax.random.fold_in(k_on, 0), on_active.shape)
-    jit_off = 1.0 + 0.18 * jax.random.normal(
-        jax.random.fold_in(k_off, 0), off_active.shape)
-    u_on = jax.random.uniform(
-        jax.random.fold_in(k_on, 1),
-        (*on_active.shape, SAMPLES_PER_TICK, 2), minval=tiny, maxval=1.0)
-    u_off = jax.random.uniform(
-        jax.random.fold_in(k_off, 1),
-        (*off_active.shape, SAMPLES_PER_TICK, 2), minval=tiny, maxval=1.0)
-
-    n = cores.shape[0]
-    nodev = jnp.stack(
-        [rho_p, threads_total, cores, fleet.delay_base, fleet.delay_scale,
-         fleet.rho_knee, fleet.oversub_slope, e_delay], axis=-1)
-    jit_all = jnp.concatenate([jit_on, jit_off], axis=1)
-    act_all = jnp.concatenate(
-        [on_active, off_active], axis=1).astype(jnp.float32)
-    u1 = jnp.concatenate(
-        [u_on[..., 0].reshape(n, -1), u_off[..., 0].reshape(n, -1)], axis=1)
-    u2 = jnp.concatenate(
-        [u_on[..., 1].reshape(n, -1), u_off[..., 1].reshape(n, -1)], axis=1)
-
-    node_hist, _delay, mean_all = fused_tick(
-        nodev, jit_all, act_all, u1, u2,
-        gamma_shape=GAMMA_SHAPE, clip_max=2.5 * metric.OVERFLOW_EDGE)
-    mean_on = mean_all[:, :S_ON]
-
-    cpu_util = jnp.minimum(total_cpu, cores) / cores
-    mem_used = mem_on.sum(-1) + mem_off.sum(-1) + 2.0
-    mem_util = jnp.minimum(mem_used, st.mem_sum) / st.mem_sum
-
-    base_rt = profiles["base_rt"][on_type]
-    sat = jnp.maximum(qps_t / profiles["qps_cap"][on_type] - 0.8, 0.0)
-    cache_term = 0.06 * base_rt * jnp.minimum(mem_used / st.mem_sum, 1.2)[:, None]
-    rt = base_rt * (1.0 + 1.5 * sat) \
-        + profiles["rt_per_runqlat"][on_type] * mean_on \
-        + cache_term \
-        + 0.06 * base_rt * jax.random.normal(k_rt, on_active.shape)
-    rt = jnp.where(on_active, jnp.maximum(rt, 0.5), 0.0)
-
-    out = {
-        "rt": rt,
-        "qps": qps_t,
-        "cpu_util": cpu_util,
-        "mem_util": mem_util,
-        "node_hist": node_hist,
-    }
-
-    new_rem = jnp.where(off_active, st.off_remaining - 1, st.off_remaining)
-    st = st.replace(off_remaining=new_rem,
-                    off_active=off_active & (new_rem > 0))
-    return st, out
-
-
-def _window_lite_pallas(state: ClusterState, profiles, fleet, t0, key,
-                        num_ticks: int):
-    """``_window_core`` counterpart for the fused path: scans
-    ``_tick_pallas`` and reduces straight to the lite per-chunk dict the
-    scan-over-windows body consumes.  Histogram bins hold small integer
-    counts, so summing per-tick node histograms here is bitwise equal to
-    the jnp path's sum-over-slots-then-chunks order."""
-
-    def tick(st, inp):
-        t, k = inp
-        return _tick_pallas(st, profiles, fleet, t, k)
-
-    keys = jax.random.split(key, num_ticks)
-    ts = t0 + jnp.arange(num_ticks, dtype=jnp.float32)
-    state, outs = jax.lax.scan(tick, state, (ts, keys))
-    lite = {
-        "rt": outs["rt"],                       # (num_ticks, N, S_ON)
-        "qps": outs["qps"].mean(0),             # (N, S_ON)
-        "cpu_util": outs["cpu_util"].mean(0),   # (N,)
-        "mem_util": outs["mem_util"].mean(0),
-        "node_hist": outs["node_hist"].sum(0),  # (N, 200)
-    }
-    return state, lite
 
 
 # --------------------------------------------------------------------------
@@ -939,7 +795,7 @@ def init_fold_state(num_nodes: int):
 
 
 def _scan_windows_impl(state, profiles, fleet, t0, keys, events, det, fc,
-                       fold0, *, use_pallas: bool = False):
+                       fold0):
     """One full experiment timeline inside jit: scan telemetry windows, each
     window = (apply that chunk's events -> CHUNK-tick rollout) per chunk,
     then fold the window's node histograms into the detector's CUSUM track
@@ -947,9 +803,6 @@ def _scan_windows_impl(state, profiles, fleet, t0, keys, events, det, fc,
 
     keys (W, C, 2), events leaves (W, C, E, ...).  Outputs are lite:
     per-window RT series, window-mean qps/cpu/mem and hotspot flags.
-
-    ``use_pallas=True`` (static) swaps the chunk body for the fused
-    ``kernels.rollout_tick`` tick; the jnp body is the reference.
     """
     from repro.control.detector import node_track_step
     from repro.control.forecast import _forecast_update
@@ -962,19 +815,14 @@ def _scan_windows_impl(state, profiles, fleet, t0, keys, events, det, fc,
             st, t = cc
             ck, cev = cxs
             st = apply_events(st, cev)
-            if use_pallas:
-                st, lite = _window_lite_pallas(st, profiles, fleet, t, ck,
-                                               CHUNK)
-            else:
-                st, summ = _window_core(st, profiles, fleet, t, ck, CHUNK)
-                lite = {
-                    "rt": summ["rt"],
-                    "qps": summ["qps"],
-                    "cpu_util": summ["cpu_util"],
-                    "mem_util": summ["mem_util"],
-                    "node_hist": summ["hist_on"].sum(1)
-                    + summ["hist_off"].sum(1),
-                }
+            st, summ = _window_core(st, profiles, fleet, t, ck, CHUNK)
+            lite = {
+                "rt": summ["rt"],
+                "qps": summ["qps"],
+                "cpu_util": summ["cpu_util"],
+                "mem_util": summ["mem_util"],
+                "node_hist": summ["hist_on"].sum(1) + summ["hist_off"].sum(1),
+            }
             return (st, t + CHUNK), lite
 
         (st, t), cs = jax.lax.scan(chunk, (st, t), (wkeys, ev))
@@ -1009,22 +857,14 @@ def _scan_windows_impl(state, profiles, fleet, t0, keys, events, det, fc,
     return final, outs
 
 
-# state (arg 0) and the detector/forecaster fold carry (arg 8) are both
-# dead after the call — their final values come back in `final` — so both
-# are donated; ``use_pallas`` selects the traced chunk body, so it must be
-# static
-scan_windows = jax.jit(_scan_windows_impl, donate_argnums=(0, 8),
-                       static_argnames=("use_pallas",))
-
-# One jitted executable per engine configuration: (stacked state?, fused
-# kernel?, device set).  vmap over a leading seed axis of `keys`; the
-# state/plan are shared (common-random-placements replay) or themselves
-# stacked per seed; the fleet is hardware, so it is always shared across
-# seeds.
+# One jitted executable per engine configuration: (stacked state?, device
+# set).  vmap over a leading seed axis of `keys`; the state/plan are shared
+# (common-random-placements replay) or themselves stacked per seed; the
+# fleet is hardware, so it is always shared across seeds.
 _ENGINE_CACHE: dict = {}
 
 
-def _batched_fn(stacked: bool, use_pallas: bool, mesh=None):
+def _batched_fn(stacked: bool, mesh=None):
     """Build (and memoize) the batched rollout executable.
 
     ``mesh=None`` is the single-device vmap; with a 1-D "seeds" mesh the
@@ -1037,15 +877,14 @@ def _batched_fn(stacked: bool, use_pallas: bool, mesh=None):
     input buffer is smaller than any batched output, so XLA could not
     reuse it anyway).
     """
-    cache_key = (stacked, use_pallas,
+    cache_key = (stacked,
                  None if mesh is None
                  else tuple(d.id for d in mesh.devices.flat))
     fn = _ENGINE_CACHE.get(cache_key)
     if fn is not None:
         return fn
-    impl = partial(_scan_windows_impl, use_pallas=use_pallas)
     batched = jax.vmap(
-        impl,
+        _scan_windows_impl,
         in_axes=((0 if stacked else None), None, None, None, 0, None, None,
                  None, None))
     if mesh is not None:
@@ -1062,10 +901,30 @@ def _batched_fn(stacked: bool, use_pallas: bool, mesh=None):
     return fn
 
 
-def _batched_call(state, profiles, t0, keys, events, det_cfg, fc_cfg,
-                  fleet, devices, use_pallas):
-    """The executable ``batched_rollout`` runs, its arguments, and the real
-    batch size (the seed axis may be padded to a device multiple)."""
+def batched_rollout(state: ClusterState, profiles, t0, keys, events,
+                    det_cfg=None, fc_cfg=None, fleet: FleetParams = None,
+                    devices: int = None):
+    """Evaluate one placement/action plan under many simulation seeds.
+
+    state: a single ClusterState (shared across seeds) or a stacked pytree
+        with a leading batch axis matching ``keys``.  A stacked state is
+        DONATED — do not reuse it after the call.
+    keys: (B, W, C, 2) per-seed chunk keys (see ``chunk_key_stream``).
+    events: ``extract_plan`` output, shared across the batch.
+    fleet: per-node delay-curve parameters, shared across the batch;
+        ``None`` means the homogeneous ``FleetParams.uniform`` fleet.
+    devices: shard the seed axis across this many devices via
+        ``shard_map``.  More devices than the runtime exposes raises
+        (``launch.mesh.make_seed_mesh``); on CPU, launch with
+        ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` to get N.
+        The batch is padded to a device multiple by repeating the last
+        seed and the padding is sliced off before returning, so results
+        are bitwise the single-device vmap results.
+
+    Returns (final, outs) with a leading B axis on every leaf: ``outs`` has
+    per-window RT series (B, W, C*CHUNK, N, S_ON), window-mean qps/cpu/mem,
+    and the folded detector's hotspot flags (B, W, N).
+    """
     det, fc = fold_configs(det_cfg, fc_cfg)
     batched_state = state.cpu_sum.ndim == 2
     num_nodes = state.cpu_sum.shape[-1]
@@ -1085,55 +944,10 @@ def _batched_call(state, profiles, t0, keys, events, det_cfg, fc_cfg,
             if batched_state:
                 state = jax.tree_util.tree_map(lambda x: x[idx], state)
 
-    fn = _batched_fn(batched_state, use_pallas, mesh)
-    args = (state, profiles, fleet, jnp.float32(t0), keys, events, det, fc,
-            fold0)
-    return fn, args, batch
-
-
-def batched_rollout(state: ClusterState, profiles, t0, keys, events,
-                    det_cfg=None, fc_cfg=None, fleet: FleetParams = None,
-                    devices: int = None, use_pallas: bool = False):
-    """Evaluate one placement/action plan under many simulation seeds.
-
-    state: a single ClusterState (shared across seeds) or a stacked pytree
-        with a leading batch axis matching ``keys``.  A stacked state is
-        DONATED — do not reuse it after the call.
-    keys: (B, W, C, 2) per-seed chunk keys (see ``chunk_key_stream``).
-    events: ``extract_plan`` output, shared across the batch.
-    fleet: per-node delay-curve parameters, shared across the batch;
-        ``None`` means the homogeneous ``FleetParams.uniform`` fleet.
-    devices: shard the seed axis across this many devices via
-        ``shard_map``.  More devices than the runtime exposes raises
-        (``launch.mesh.make_seed_mesh``); on CPU, launch with
-        ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` to get N.
-        The batch is padded to a device multiple by repeating the last
-        seed and the padding is sliced off before returning, so results
-        are bitwise the single-device vmap results.
-    use_pallas: run the fused ``kernels.rollout_tick`` tick kernel instead
-        of the default-and-reference jnp tick.
-
-    Returns (final, outs) with a leading B axis on every leaf: ``outs`` has
-    per-window RT series (B, W, C*CHUNK, N, S_ON), window-mean qps/cpu/mem,
-    and the folded detector's hotspot flags (B, W, N).
-    """
-    fn, args, batch = _batched_call(state, profiles, t0, keys, events,
-                                    det_cfg, fc_cfg, fleet, devices,
-                                    use_pallas)
-    final, outs = fn(*args)
-    if args[4].shape[0] != batch:
+    fn = _batched_fn(batched_state, mesh)
+    final, outs = fn(state, profiles, fleet, jnp.float32(t0), keys, events,
+                     det, fc, fold0)
+    if keys.shape[0] != batch:
         final = jax.tree_util.tree_map(lambda x: x[:batch], final)
         outs = jax.tree_util.tree_map(lambda x: x[:batch], outs)
     return final, outs
-
-
-def lower_batched_rollout(state: ClusterState, profiles, t0, keys, events,
-                          det_cfg=None, fc_cfg=None,
-                          fleet: FleetParams = None, devices: int = None,
-                          use_pallas: bool = False):
-    """``jax.stages.Lowered`` of the program ``batched_rollout`` runs for
-    these arguments.  ``.compile().as_text()`` is what the device runs; a
-    compiled Pallas kernel shows there as ``tpu_custom_call``."""
-    fn, args, _ = _batched_call(state, profiles, t0, keys, events, det_cfg,
-                                fc_cfg, fleet, devices, use_pallas)
-    return fn.lower(*args)

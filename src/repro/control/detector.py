@@ -92,9 +92,9 @@ def node_track_step(hist, mu, cusum, steps, node_hists, decay, alpha, slack,
     baseline, accumulate drift, trip/consume the flags.
 
     Shared by ``_detector_update`` (the host loop's jit'd call) and the
-    scanned rollout core (``repro.cluster.state.scan_windows`` folds this
-    into its window carry) — one definition, so the in-scan detector is the
-    same math as the interactive one.  Returns
+    scanned rollout core (``repro.cluster.state.batched_rollout`` folds
+    this into its window carry) — one definition, so the in-scan detector
+    is the same math as the interactive one.  Returns
     (hist, avg, p_tail, mu, cusum_after_reset, cusum_trip, drift_trip,
     acute_trip, raw_hot, hot); the caller owns the ``steps`` increment.
     """

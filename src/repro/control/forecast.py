@@ -117,7 +117,7 @@ def _forecast_update(A, b, err, count, t, y, active, decay, ridge, alpha,
     the *old* fit made for this window (the calibration signal).
 
     Also reused verbatim inside the scanned rollout core
-    (``repro.cluster.state.scan_windows`` folds it into the window carry),
+    (``repro.cluster.state.batched_rollout`` folds it into the window carry),
     so the in-scan forecaster moments are the same math as this host loop's.
     """
     x = _features(t)                                   # (F,)

@@ -588,16 +588,13 @@ class ControlLoop:
         k = k or cluster.CHUNK
         done = 0
         rec = self._recorder
-        # the scanned single-dispatch path when the cluster provides it
-        # (bit-identical to the chunk loop); plain rollout otherwise
-        roll = getattr(cluster, "rollout_scan", cluster.rollout)
         while done < num_ticks:
             t0 = cluster.t
             with self.timers.phase("rollout"):
                 # async dispatch: block inside the timed region so the
                 # device compute is attributed to "rollout", not to
                 # whichever later phase happens to synchronize first
-                out = roll(min(k, num_ticks - done))
+                out = cluster.rollout(min(k, num_ticks - done))
                 jax.block_until_ready(out)
             progress = int(cluster.t - t0)
             if progress <= 0:

@@ -1,10 +1,9 @@
 """Persistent XLA compilation cache wiring.
 
 Cold compiles dominate the first call of every rollout program: the
-scanned window, the batched replay, the fused tick kernel.  JAX's
-persistent compilation cache keys executables by (HLO, jaxlib version,
-backend, flags), so a warm directory turns those compiles into disk
-reads.  The directory is part of what a later run must find again, so it
+scanned window and the batched replay.  JAX's persistent compilation
+cache keys executables by (HLO, jaxlib version, backend, flags), so a
+warm directory turns those compiles into disk reads.  The directory is part of what a later run must find again, so it
 is never a temporary or per-process name.
 
 Entry points (``chip_smoke.py``, ``benchmarks/run.py`` and each bench's

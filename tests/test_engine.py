@@ -1,20 +1,16 @@
 """Device-parallel, compile-once rollout engine.
 
-Four properties carry this layer:
+Three properties carry this layer:
 
 * **Shard parity** — ``batched_rollout(devices=N)`` (shard_map over a 1-D
   "seeds" mesh) is bitwise the single-device vmap, including the padding
   path when the batch does not divide the device count.
-* **Donation safety** — the donated carries (state at ``rollout_chunks`` /
-  ``scan_windows``, the fold carry, the stacked batched state) really are
-  consumed, and consuming them does not perturb results (the golden
+* **Donation safety** — the donated carries (state at ``rollout_chunks``,
+  the stacked batched state) really are consumed, and consuming them does not perturb results (the golden
   digests in test_fleet.py stay bitwise on the same entry points).
 * **Compile-once bucketing** — two different plans in the same
   power-of-two size class replay through ONE compiled executable, and the
   bucketing padding is bitwise invisible on the real window prefix.
-* **Fused-kernel parity** — the Pallas tick kernel matches its jnp
-  reference exactly in interpret mode (unit) and the full engine within
-  float tolerance (integration).
 """
 from __future__ import annotations
 
@@ -119,20 +115,11 @@ def test_shard_map_parity_two_devices_subprocess():
 
 
 def test_donated_carries_are_consumed():
-    """scan_windows donates the state and fold carry; rollout_chunks
-    donates the state.  On backends implementing donation the inputs must
-    be dead afterwards — reuse would silently read freed buffers."""
-    state0, profiles, keys, events = _scenario(seeds=(0,))
+    """rollout_chunks donates the state.  On backends implementing donation
+    the input must be dead afterwards — reuse would silently read freed
+    buffers."""
+    profiles = _profiles()
     fleet = cstate.FleetParams.uniform(3)
-    det, fc = cstate.fold_configs()
-    fold0 = cstate.init_fold_state(3)
-    final, _ = cstate.scan_windows(state0, profiles, fleet, jnp.float32(0.0),
-                                   keys[0], events, det, fc, fold0)
-    assert state0.cpu_sum.is_deleted()
-    assert fold0[0].is_deleted()
-    # the returned carry is alive and well-formed
-    assert final["state"].cpu_sum.shape == (3,)
-
     st = cstate.ClusterState.create(3)
     _, ks = cstate.chunk_key_stream(jax.random.PRNGKey(0), 4)
     new_st, _ = cstate.rollout_chunks(st, profiles, fleet, 0.0, ks)
@@ -190,7 +177,7 @@ def test_same_size_class_plans_share_one_executable():
     seeds = (0, 1)
     state0 = cstate.ClusterState.create(5)
     profiles = _profiles()
-    fn = cstate._batched_fn(stacked=False, use_pallas=False)
+    fn = cstate._batched_fn(stacked=False)
     before = fn._cache_size()
     walls = []
     for log in (log_a, log_b):
@@ -212,54 +199,6 @@ def test_same_size_class_plans_share_one_executable():
 def test_next_pow2():
     assert [cstate._next_pow2(n) for n in (0, 1, 2, 3, 5, 8, 9)] \
         == [1, 1, 2, 4, 8, 8, 16]
-
-
-# ------------------------------------------------------------ pallas parity
-
-
-def test_fused_tick_unit_parity():
-    """Interpret-mode kernel vs the pure-jnp oracle: exact, including the
-    node-padding path (N=5 on block=4)."""
-    from repro.kernels.rollout_tick import fused_tick, fused_tick_reference
-
-    n, s, k = 5, 14, 16
-    ks = jax.random.split(jax.random.PRNGKey(3), 5)
-    rho = jax.random.uniform(ks[0], (n,), minval=0.1, maxval=1.3)
-    nodev = jnp.stack(
-        [rho, 3.0 + jnp.arange(n, dtype=jnp.float32), jnp.full((n,), 8.0),
-         jnp.full((n,), 3.0), jnp.full((n,), 55.0), jnp.full((n,), 0.05),
-         jnp.full((n,), 0.15), jax.random.normal(ks[1], (n,))], axis=-1)
-    jit_all = 1.0 + 0.18 * jax.random.normal(ks[2], (n, s))
-    act = (jax.random.uniform(ks[3], (n, s)) > 0.4).astype(jnp.float32)
-    u = jax.random.uniform(ks[4], (n, s * k, 2),
-                           minval=jnp.finfo(jnp.float32).tiny, maxval=1.0)
-    h1, d1, m1 = fused_tick(nodev, jit_all, act, u[..., 0], u[..., 1],
-                            block=4, interpret=True)
-    h2, d2, m2 = fused_tick_reference(nodev, jit_all, act,
-                                      u[..., 0], u[..., 1])
-    np.testing.assert_array_equal(np.asarray(h1), np.asarray(h2))
-    np.testing.assert_array_equal(np.asarray(d1), np.asarray(d2))
-    np.testing.assert_array_equal(np.asarray(m1), np.asarray(m2))
-    # every active slot contributed its full sample count
-    assert float(h1.sum()) == float(act.sum()) * k
-
-
-def test_use_pallas_engine_parity():
-    """The fused engine against the jnp reference on the same scenario:
-    histograms/flags and the XLA-side telemetry are exact, the RT stream
-    (kernel-computed runqlat means feed it) agrees to float tolerance."""
-    state0, profiles, keys, events = _scenario()
-    ref = cstate.batched_rollout(state0, profiles, 0.0, keys, events)
-    got = cstate.batched_rollout(state0, profiles, 0.0, keys, events,
-                                 use_pallas=True)
-    np.testing.assert_array_equal(np.asarray(ref[1]["hot"]),
-                                  np.asarray(got[1]["hot"]))
-    for k in ("qps", "cpu_util", "mem_util"):
-        np.testing.assert_array_equal(np.asarray(ref[1][k]),
-                                      np.asarray(got[1][k]), err_msg=k)
-    np.testing.assert_allclose(np.asarray(ref[1]["rt"]),
-                               np.asarray(got[1]["rt"]), rtol=1e-5,
-                               atol=1e-5)
 
 
 # ---------------------------------------------------------- phase timers
@@ -298,7 +237,7 @@ def test_rollout_span_holds_the_device_wait(monkeypatch, controlled):
     loop = ControlLoop(quant) if controlled else None
     pods, gaps = _arrival_trace(6, seed=3)
     run_experiment(ICOScheduler(quant), pods, gaps, num_nodes=4, seed=5,
-                   fast=True, control_loop=loop, control_window=40)
+                   control_loop=loop, control_window=40)
     assert waits and all(w[-1:] == ("repro.loop.rollout",) for w in waits)
     want = {"repro.loop.rollout"}
     if controlled:
@@ -326,11 +265,11 @@ def test_rollout_phase_attribution():
     quant = InterferenceQuantifier(lambda x: np.asarray(x)[:, 0] * 0.1)
     sched = ICOScheduler(quant)
     pods, gaps = _arrival_trace(10, seed=3)
-    run_experiment(sched, pods, gaps, num_nodes=6, seed=5, fast=True,
+    run_experiment(sched, pods, gaps, num_nodes=6, seed=5,
                    control_loop=ControlLoop(quant), control_window=40)
     loop = ControlLoop(quant)
     t0 = time.time()
-    res = run_experiment(sched, pods, gaps, num_nodes=6, seed=5, fast=True,
+    res = run_experiment(sched, pods, gaps, num_nodes=6, seed=5,
                          control_loop=loop, control_window=40)
     wall = time.time() - t0
     totals = dict(loop.timers.totals)

@@ -119,61 +119,62 @@ def test_r1_silent_on_pure_code_and_out_of_scope_modules():
     assert found == []
 
 
-def test_r1_covers_fleet_prefilter_roots():
-    """repro.cluster.fleet is a jit-root module: a host call reachable
-    from its jit'd top-k prefilter must fire R1."""
-    assert "repro.cluster.fleet" in layers.JIT_ROOT_MODULES
-    fixture = sf("repro.cluster.fleet", """\
-        import time
-        from functools import partial
+_R1_ROOT_FIXTURE = """\
+    import time
+    from functools import partial
 
-        import jax
+    import jax
+    import jax.numpy as jnp
 
-        @partial(jax.jit, static_argnames=("k",))
-        def topk(scores, k):
-            t = time.time()
-            return jax.lax.top_k(scores, k)
-    """)
-    _, found = rules_hit(fixture, "R1")
-    assert len(found) == 1 and "time.time" in found[0].message
+    def _stamp(x):
+        t = time.time()
+        return x
+
+    def _pull(x):
+        return float(x.sum())
+
+    def _sync(x):
+        return x.max().item()
+
+    @jax.jit
+    def clocked(x):
+        return _stamp(x) * 2.0
+
+    @jax.jit
+    def pulled(x):
+        return jnp.asarray(_pull(x))
+
+    @partial(jax.jit, static_argnames=("k",))
+    def topk(scores, k):
+        return jax.lax.top_k(scores * _sync(scores), k)
+"""
+
+# Written out, not read from layers: dropping a module from
+# JIT_ROOT_MODULES must fail this test, not shrink it.
+_JIT_ROOT_MODULES = (
+    "repro.cluster.experiment",
+    "repro.cluster.fleet",
+    "repro.cluster.state",
+    "repro.control.detector",
+    "repro.control.forecast",
+)
 
 
-def test_r1_covers_replay_stats_roots():
-    """repro.cluster.experiment is a jit-root module: a host pull reachable
-    from the replay's jit'd statistics program must fire R1."""
-    assert "repro.cluster.experiment" in layers.JIT_ROOT_MODULES
-    fixture = sf("repro.cluster.experiment", """\
-        import jax
-        import jax.numpy as jnp
-
-        def _pooled(rt):
-            return float(rt.sum())
-
-        @jax.jit
-        def _replay_stats(rt):
-            return jnp.asarray(_pooled(rt))
-    """)
-    _, found = rules_hit(fixture, "R1")
-    assert len(found) == 1 and "float()" in found[0].message
-
-
-def test_r1_covers_rollout_tick_roots():
-    """repro.kernels.rollout_tick is a jit-root module: a host call inside
-    the jitted fused-tick wrapper must fire R1."""
-    assert "repro.kernels.rollout_tick" in layers.JIT_ROOT_MODULES
-    fixture = sf("repro.kernels.rollout_tick", """\
-        import time
-        from functools import partial
-
-        import jax
-
-        @partial(jax.jit, static_argnames=("block",))
-        def fused_tick(x, block):
-            t = time.time()
-            return x * 2.0
-    """)
-    _, found = rules_hit(fixture, "R1")
-    assert len(found) == 1 and "time.time" in found[0].message
+@pytest.mark.parametrize("module", _JIT_ROOT_MODULES)
+def test_r1_covers_every_jit_root_module(module):
+    """Each jit-root module seeds R1's call closure: a host call that one
+    of its jitted functions reaches through a helper fires R1, for each
+    root form (bare ``@jax.jit`` and ``@partial(jax.jit, ...)``) and each
+    host-call flavour (a ``time.time`` clock read, a ``float()`` pull, an
+    ``.item()`` sync)."""
+    assert set(layers.JIT_ROOT_MODULES) == set(_JIT_ROOT_MODULES)
+    _, found = rules_hit(sf(module, _R1_ROOT_FIXTURE), "R1")
+    messages = sorted(f.message for f in found)
+    assert len(messages) == 3, messages
+    assert any("time.time" in m and "clocked" in m for m in messages), \
+        messages
+    assert any("float()" in m and "pulled" in m for m in messages), messages
+    assert any(".item()" in m and "topk" in m for m in messages), messages
 
 
 def test_r1_suppression():
@@ -396,12 +397,12 @@ def test_r4_fleet_stays_below_control():
 def test_r4_kernels_stay_below_control():
     """The kernels row: leaf accelerator code must not reach repro.control,
     even transitively."""
-    direct = sf("repro.kernels.rollout_tick",
+    direct = sf("repro.kernels.runqlat_hist",
                 "from repro.control import policy\n")
     _, found = rules_hit(direct, "R4")
     assert found and all("repro.control" in f.message for f in found)
 
-    mid = sf("repro.kernels.rollout_tick",
+    mid = sf("repro.kernels.runqlat_hist",
              "from repro.kernels import helper\n")
     helper = sf("repro.kernels.helper",
                 "from repro.control import actions\n")

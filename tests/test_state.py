@@ -1,10 +1,11 @@
 """Batched rollout core: ClusterState pytree, scan/vmap paths, event replay.
 
-The load-bearing bar here is **parity**: the scanned core (`rollout_scan`,
-`scan_windows`, `batched_rollout`) must reproduce the legacy per-chunk
-Python loop — same key stream, same telemetry, same placements — so the
-fast paths in `run_experiment` / `replay_plan_batched` measure the same
-simulation the shell-driven runs do.
+The load-bearing bar here is **parity**: the scanned core
+(`Cluster.rollout`, `batched_rollout`) must reproduce an eager loop of
+per-chunk `_window_core` calls (`_eager_rollout`, kept here as the oracle)
+— same key stream, same telemetry, same placements — so `run_experiment`
+and `replay_plan_batched` measure the same simulation the shell-driven
+runs do.
 """
 import contextlib
 import dataclasses
@@ -86,11 +87,32 @@ def _seeded_cluster(seed=5):
     return c
 
 
-def test_rollout_scan_matches_rollout():
+_window_jit = jax.jit(cstate._window_core, static_argnames="num_ticks")
+
+
+def _eager_rollout(cluster, num_ticks):
+    """The oracle ``Cluster.rollout`` must reproduce: one jitted
+    ``_window_core`` dispatch per chunk, each keyed by an iterative split of
+    the cluster's key, the summaries merged host-side."""
+    chunks = max(1, -(-num_ticks // CHUNK))
+    parts = []
+    for _ in range(chunks):
+        cluster.key, k = jax.random.split(cluster.key)
+        cluster.state, summary = _window_jit(
+            cluster.state, cluster.profiles, cluster.fleet_params,
+            jnp.float32(cluster.t), k, num_ticks=CHUNK)
+        cluster.t += CHUNK
+        parts.append(summary)
+    cluster.last = jax.tree.map(np.asarray, cstate.merge_summaries(parts))
+    cluster.reconcile()
+    return cluster.last
+
+
+def test_rollout_matches_eager_chunk_loop():
     """Bitwise parity: same key stream, same telemetry, same final state."""
     a, b = _seeded_cluster(), _seeded_cluster()
-    sa = a.rollout(40)
-    sb = b.rollout_scan(40)
+    sa = _eager_rollout(a, 40)
+    sb = b.rollout(40)
     for k in sa:
         np.testing.assert_array_equal(np.asarray(sa[k]), np.asarray(sb[k]),
                                       err_msg=k)
@@ -98,7 +120,7 @@ def test_rollout_scan_matches_rollout():
     for c in (a, b):
         c.migrate(0, 3)
         c.resize(2, cores=2.0)
-    sa, sb = a.rollout(40), b.rollout_scan(40)
+    sa, sb = _eager_rollout(a, 40), b.rollout(40)
     for k in sa:
         np.testing.assert_array_equal(np.asarray(sa[k]), np.asarray(sb[k]),
                                       err_msg=k)
@@ -147,7 +169,7 @@ def test_event_replay_matches_shell():
                                   np.asarray(c.state.off_active))
 
 
-def _tiny_experiment(fast, plan_out=None):
+def _tiny_experiment(plan_out=None):
     from repro.cluster.experiment import _arrival_trace, run_experiment
     from repro.core import ICOScheduler, InterferenceQuantifier
 
@@ -155,22 +177,23 @@ def _tiny_experiment(fast, plan_out=None):
         lambda x: np.asarray(x)[:, 0] * 0.1))
     pods, gaps = _arrival_trace(12, seed=3)
     return run_experiment(sched, pods, gaps, num_nodes=6, seed=5,
-                          fast=fast, plan_out=plan_out)
+                          plan_out=plan_out)
 
 
-def test_run_experiment_fast_path_matches_legacy():
-    r_fast, r_slow = _tiny_experiment(True), _tiny_experiment(False)
-    assert (r_fast.placed, r_fast.rejected) == (r_slow.placed, r_slow.rejected)
+def test_run_experiment_matches_eager_chunk_loop(monkeypatch):
+    got = _tiny_experiment()
+    monkeypatch.setattr(Cluster, "rollout", _eager_rollout)
+    want = _tiny_experiment()
+    assert (got.placed, got.rejected) == (want.placed, want.rejected)
     for f in ("avg_rt", "p90_rt", "p99_rt", "cpu_util_std", "mem_util_std"):
-        assert np.isclose(getattr(r_fast, f), getattr(r_slow, f),
-                          rtol=1e-6), f
+        assert getattr(got, f) == getattr(want, f), f
 
 
 def test_replay_plan_batched_reference_parity():
     from repro.cluster.experiment import replay_plan_batched
 
     plan = {}
-    ref = _tiny_experiment(True, plan_out=plan)
+    ref = _tiny_experiment(plan_out=plan)
     batch = replay_plan_batched(plan, sim_seeds=[5, 6])
     assert batch["num_windows"] > 0 and len(batch["seeds"]) == 2
     by_seed = {e["sim_seed"]: e for e in batch["seeds"]}

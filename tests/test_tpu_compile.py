@@ -3,8 +3,8 @@
 The TPU compiler is installed with JAX, and it compiles for a chip that is
 described rather than attached.  These tests compile the orchestrator's
 hot programs at real widths for one chip of a described ``v5e:2x2``
-topology: the fused tick kernel, the scanned rollout window and the
-admission top-k prefilter.  They catch what interpret mode cannot (Mosaic
+topology: the seed-batched replay engine, the scanned rollout window and
+the admission top-k prefilter.  They catch what interpret mode cannot (Mosaic
 layout limits, VMEM budgets, unsupported lowerings) without a chip.
 Nothing runs, so they say nothing about results or times.
 
@@ -23,11 +23,14 @@ import pytest
 from repro.cluster import state as cstate
 from repro.cluster import workloads as W
 from repro.cluster.fleet import topk_candidates
-from repro.kernels.rollout_tick import fused_tick
 
 NODES = 1000          # the smoke fleet: make_fleet(1000, DEFAULT_MIX)
 ADMIT_NODES = 5000    # the largest admission view bench_scheduler_latency uses
-SLOTS = cstate.S_ON + cstate.S_OFF
+# the paper12.replay cell: 20 seeds a call, 12 nodes, 3 days of 40-tick
+# windows bucketed to 256, 4 chunks a window, and its plan's bucketed
+# events per chunk
+REPLAY_SEEDS, REPLAY_NODES, REPLAY_WINDOWS, REPLAY_CPW, REPLAY_EVENTS = (
+    20, 12, 256, 4, 8)
 
 
 @pytest.fixture(scope="module")
@@ -52,21 +55,37 @@ def one_chip():
 
 
 def _shapes(tree, sharding):
+    # a Python scalar is traced weakly typed, as the call itself traces it
     return jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype,
-                                       sharding=sharding), tree)
+        lambda x: jax.ShapeDtypeStruct(
+            jnp.shape(x), jnp.asarray(x).dtype, sharding=sharding,
+            weak_type=isinstance(x, (int, float))), tree)
 
 
-def test_fused_tick_compiles_for_v5e(one_chip):
-    def f32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
-
-    k = cstate.SAMPLES_PER_TICK
-    compiled = fused_tick.lower(
-        f32(NODES, 8), f32(NODES, SLOTS), f32(NODES, SLOTS),
-        f32(NODES, SLOTS * k), f32(NODES, SLOTS * k),
-        interpret=False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def test_replay_engine_compiles_for_v5e(one_chip):
+    """``batched_rollout``'s shared-state engine at ``paper12.replay``'s
+    shapes: the program that cell replays its plan with."""
+    # every event of the plan in its first chunk: the events-per-chunk
+    # dimension is then the cell's bucketed width
+    log = [("place_on", 0.0, n % REPLAY_NODES, n // REPLAY_NODES, 0, 300.0,
+            0.4) for n in range(REPLAY_EVENTS)]
+    events = cstate.extract_plan(log, 0.0, REPLAY_WINDOWS, REPLAY_CPW,
+                                 bucket=True)
+    assert events["op"].shape == (REPLAY_WINDOWS, REPLAY_CPW, REPLAY_EVENTS)
+    det, fc = cstate.fold_configs()
+    args = (cstate.ClusterState.create(REPLAY_NODES), W.online_arrays(),
+            cstate.FleetParams.uniform(REPLAY_NODES), jnp.float32(0.0))
+    args = _shapes(args, one_chip) + (
+        jax.ShapeDtypeStruct(
+            (REPLAY_SEEDS, REPLAY_WINDOWS, REPLAY_CPW, 2), jnp.uint32,
+            sharding=one_chip),) + _shapes(
+        (events, det, fc, cstate.init_fold_state(REPLAY_NODES)), one_chip)
+    compiled = cstate._batched_fn(stacked=False).lower(*args).compile()
+    assert compiled.memory_analysis() is not None
+    _, outs = compiled.out_info
+    assert outs["rt"].shape == (REPLAY_SEEDS, REPLAY_WINDOWS,
+                                REPLAY_CPW * cstate.CHUNK, REPLAY_NODES,
+                                cstate.S_ON)
 
 
 def test_rollout_chunks_compiles_for_v5e(one_chip):
