@@ -457,8 +457,10 @@ def replay_inputs(plan: dict, sim_seeds, window_ticks: int = 40,
 
     Returns ``{"state", "profiles", "keys", "events", "fleet"}`` (the
     call's arguments; ``fleet`` is None for the uniform fleet) plus the
-    trace geometry ``t_end``, ``num_windows``, ``padded_windows`` and
-    ``span`` (ticks per window).  ``keys`` is every seed's chunk key
+    trace geometry ``t_end``, ``num_windows``, ``padded_windows``,
+    ``computed_windows`` (the windows that hold a tick before ``t_end``:
+    the count ``batched_rollout`` runs) and ``span`` (ticks per window).
+    ``keys`` is every seed's chunk key
     stream, (B, padded_windows, cpw, 2), built by two compiled programs
     (``state.seed_keys``, ``state.replay_key_stream``) and bitwise the
     eager per-seed split loop; the stream is prefix-stable, so padding
@@ -485,6 +487,7 @@ def replay_inputs(plan: dict, sim_seeds, window_ticks: int = 40,
         events = cstate.extract_plan(log, 0.0, num_windows, cpw,
                                      bucket=bucket)
     padded_windows = events["op"].shape[0]
+    span = cpw * cstate.CHUNK
     with timers.phase("keys"):
         keys = cstate.replay_key_stream(cstate.seed_keys(sim_seeds),
                                         padded_windows, cpw)
@@ -500,7 +503,8 @@ def replay_inputs(plan: dict, sim_seeds, window_ticks: int = 40,
     return {"state": state0, "profiles": profiles, "keys": keys,
             "events": events, "fleet": fleet_params, "t_end": t_end,
             "num_windows": num_windows, "padded_windows": padded_windows,
-            "span": cpw * cstate.CHUNK}
+            "computed_windows": min(padded_windows, -(-t_end // span)),
+            "span": span}
 
 
 # np.percentile's default ("linear") method on float32 samples, as numpy 2
@@ -570,26 +574,26 @@ def _replay_stats(rt, cpu_util, mem_util, hot, t_end, settle_ticks,
     """The per-seed answers of a replay, from ``batched_rollout``'s
     outputs where they lie: ``_pooled_rt`` over the ticks in
     [30, ``t_end``), the mean over the arrival-phase windows of the
-    cross-node CPU/memory utilization std (every window for a trace too
-    short to have one), and the windows below ``num_windows`` in which
-    the detector flagged a node.  Every reduction is per seed, so a
-    seed-sharded input stays sharded.  Returns a dict of (B,) arrays."""
+    cross-node CPU/memory utilization std (every window below
+    ``num_windows`` for a trace too short to have one), and the windows
+    below ``num_windows`` in which the detector flagged a node.  Every
+    reduction is per seed, so a seed-sharded input stays sharded.
+    Returns a dict of (B,) arrays."""
     padded_windows, span = rt.shape[1], rt.shape[2]
     w_start = jnp.arange(padded_windows) * span
     tick = w_start[:, None] + jnp.arange(span)[None, :]
     pooled = _pooled_rt(rt, (tick >= _WARMUP_TICKS) & (tick < t_end))
+    # only the real prefix counts: the engine runs no tick of a padded
+    # window, whose outputs are zeros
+    real = jnp.arange(padded_windows) < num_windows
     wins = ((w_start >= _WARMUP_TICKS)
             & (w_start + span <= t_end - settle_ticks))
-    wins = wins | ~jnp.any(wins)
+    wins = wins | (real & ~jnp.any(wins))
 
     def util_std(util):
         std = jnp.std(100 * util, axis=-1)                    # (B, W)
         return jnp.sum(jnp.where(wins, std, 0.0), axis=1) / jnp.sum(wins)
 
-    # padded windows simulate past t_end and could trip the detector; only
-    # the real prefix counts (it is bitwise the unbucketed scan's: the fold
-    # carry runs front-to-back)
-    real = jnp.arange(padded_windows) < num_windows
     return dict(pooled, cpu_util_std=util_std(cpu_util),
                 mem_util_std=util_std(mem_util),
                 hot_windows=jnp.sum(jnp.any(hot, -1) & real, axis=1,
@@ -618,19 +622,22 @@ def replay_plan_batched(
 
     ``bucket=True`` (default) pads the event plan to its power-of-two size
     class (``extract_plan(..., bucket=True)``) so every same-class plan in
-    a scenario suite reuses ONE compiled executable; the padded windows sit
-    past ``t_end`` and are already excluded by the RT/util masks, so the
-    numbers are bitwise those of the unbucketed replay.  ``devices=N``
-    shards the seed axis across host devices (``state.batched_rollout``'s
-    shard_map path).
+    a scenario suite reuses ONE compiled executable.  The engine is told
+    how many windows are real (``computed_windows``, traced data, so the
+    size class still compiles once) and runs no tick of the padded ones
+    past ``t_end``, so the numbers are bitwise those of the unbucketed
+    replay.  ``devices=N`` shards the seed axis across host devices
+    (``state.batched_rollout``'s shard_map path).
 
     Returns ``{"seeds": [...], "wall_s": float, "phases": dict,
-    "num_windows": int, "padded_windows": int}``; each per-seed entry
-    carries avg/p90/p99 RT, arrival-phase cross-node cpu/mem util std
-    (window-level, so not directly comparable with the reference's
-    variable-length control windows), and the folded detector's
-    hot-window count.  Warmup ticks
-    (< 30) and any padding past ``t_end`` are excluded from the RT pool,
+    "num_windows": int, "padded_windows": int, "computed_windows": int}``
+    (the plan's windows, the compiled size class, and the windows the
+    engine ran: ``padded_windows - computed_windows`` were skipped); each
+    per-seed entry carries avg/p90/p99 RT, arrival-phase cross-node
+    cpu/mem util std (window-level, so not directly comparable with the
+    reference's variable-length control windows), and the folded
+    detector's hot-window count.  Warmup ticks (< 30) and any tick past
+    ``t_end`` are excluded from the RT pool,
     matching the reference driver's sampling span.  The statistics are
     computed where the series lie (``_replay_stats``, one compiled program
     of exact masked order statistics), so only the per-seed numbers come
@@ -657,7 +664,8 @@ def replay_plan_batched(
         with timers.phase("engine"):
             _, outs = cstate.batched_rollout(
                 inp["state"], inp["profiles"], 0.0, inp["keys"],
-                inp["events"], fleet=inp["fleet"], devices=devices)
+                inp["events"], fleet=inp["fleet"], devices=devices,
+                num_windows=inp["computed_windows"])
             outs = jax.block_until_ready(outs)
 
         with timers.phase("reduce"):
@@ -674,7 +682,8 @@ def replay_plan_batched(
             } for i, s in enumerate(sim_seeds)]
     phases = timers.pop_window()
     return {"seeds": seeds_out, "wall_s": phases["engine"], "phases": phases,
-            "num_windows": num_windows, "padded_windows": padded_windows}
+            "num_windows": num_windows, "padded_windows": padded_windows,
+            "computed_windows": inp["computed_windows"]}
 
 
 def compare_schedulers(

@@ -61,8 +61,9 @@ Compile-once engine properties:
   **size-class buckets** (events-per-chunk and window count), so every
   same-class plan of a scenario suite or optimizer candidate sweep replays
   through ONE compiled executable instead of recompiling per plan.  NOOP
-  padding events are identity transforms and padded windows extend the key
-  stream prefix-stably, so the un-padded prefix is bitwise unchanged.
+  padding events are identity transforms, and ``batched_rollout`` takes the
+  plan's real window count as traced data and skips the padded windows, so
+  the un-padded prefix is bitwise unchanged and the padding costs no ticks.
 
 The per-window outputs are deliberately "lite" (RT series, window-mean
 utilization, folded hotspot flags) — stacking per-tick slot histograms
@@ -440,10 +441,10 @@ def extract_plan(log, t0: float, num_windows: int,
     shares one traced shape, so an entire scenario suite or optimizer
     candidate sweep replays through a single compiled executable.  The
     padding is semantically inert: NOOPs are identity transforms, and a
-    padded window only appends chunks past the plan's real span (the
-    per-seed chunk-key stream is prefix-stable), so the un-padded prefix
-    of the replay is bitwise unchanged — callers mask ticks ``>= t_end``
-    exactly as they already do for chunk-rounding overshoot.
+    padded window lies past the plan's real span, where
+    ``batched_rollout(..., num_windows=<real count>)`` runs no tick of it
+    (the per-seed chunk-key stream is prefix-stable), so the un-padded
+    prefix of the replay is bitwise unchanged.
     """
     buckets: list[list] = [[] for _ in range(num_windows * chunks_per_window)]
     for entry in log:
@@ -795,7 +796,7 @@ def init_fold_state(num_nodes: int):
 
 
 def _scan_windows_impl(state, profiles, fleet, t0, keys, events, det, fc,
-                       fold0):
+                       fold0, num_windows):
     """One full experiment timeline inside jit: scan telemetry windows, each
     window = (apply that chunk's events -> CHUNK-tick rollout) per chunk,
     then fold the window's node histograms into the detector's CUSUM track
@@ -803,13 +804,17 @@ def _scan_windows_impl(state, profiles, fleet, t0, keys, events, det, fc,
 
     keys (W, C, 2), events leaves (W, C, E, ...).  Outputs are lite:
     per-window RT series, window-mean qps/cpu/mem and hotspot flags.
+    Only the first ``num_windows`` (a traced int32 scalar) windows run; a
+    window past them passes the carry through and emits zeros (``hot``
+    False), so one executable serves every real length of a size class.
+    The predicate is unbatched under the seed vmap, so it stays a real
+    conditional and the skipped windows cost no ticks.
     """
     from repro.control.detector import node_track_step
     from repro.control.forecast import _forecast_update
 
-    def window(carry, xs):
+    def run(carry, wkeys, ev):
         st, t, dh, dmu, dcu, dsteps, A, b, err, cnt = carry
-        wkeys, ev = xs
 
         def chunk(cc, cxs):
             st, t = cc
@@ -848,8 +853,18 @@ def _scan_windows_impl(state, profiles, fleet, t0, keys, events, det, fc,
         }
         return (st, t, dh, dmu, dcu, dsteps, A, b, err, cnt), out
 
+    def skip(carry, wkeys, ev):
+        out = jax.eval_shape(run, carry, wkeys, ev)[1]
+        return carry, jax.tree_util.tree_map(
+            lambda s: jnp.zeros(s.shape, s.dtype), out)
+
+    def window(carry, xs):
+        w, wkeys, ev = xs
+        return jax.lax.cond(w < num_windows, run, skip, carry, wkeys, ev)
+
     carry0 = (state, jnp.float32(t0)) + fold0
-    carry, outs = jax.lax.scan(window, carry0, (keys, events))
+    ws = jnp.arange(keys.shape[0], dtype=jnp.int32)
+    carry, outs = jax.lax.scan(window, carry0, (ws, keys, events))
     st, t, dh, dmu, dcu, dsteps, A, b, err, cnt = carry
     final = {"state": st, "t": t, "det_hist": dh, "det_mu": dmu,
              "det_cusum": dcu, "fc_A": A, "fc_b": b, "fc_err": err,
@@ -886,7 +901,7 @@ def _batched_fn(stacked: bool, mesh=None):
     batched = jax.vmap(
         _scan_windows_impl,
         in_axes=((0 if stacked else None), None, None, None, 0, None, None,
-                 None, None))
+                 None, None, None))
     if mesh is not None:
         from jax.sharding import PartitionSpec
 
@@ -894,7 +909,7 @@ def _batched_fn(stacked: bool, mesh=None):
         batched = jax.shard_map(
             batched, mesh=mesh,
             in_specs=((seeds if stacked else rep), rep, rep, rep, seeds,
-                      rep, rep, rep, rep),
+                      rep, rep, rep, rep, rep),
             out_specs=seeds, check_vma=False)
     fn = jax.jit(batched, donate_argnums=(0,) if stacked else ())
     _ENGINE_CACHE[cache_key] = fn
@@ -903,7 +918,7 @@ def _batched_fn(stacked: bool, mesh=None):
 
 def batched_rollout(state: ClusterState, profiles, t0, keys, events,
                     det_cfg=None, fc_cfg=None, fleet: FleetParams = None,
-                    devices: int = None):
+                    devices: int = None, num_windows: int = None):
     """Evaluate one placement/action plan under many simulation seeds.
 
     state: a single ClusterState (shared across seeds) or a stacked pytree
@@ -920,6 +935,12 @@ def batched_rollout(state: ClusterState, profiles, t0, keys, events,
         The batch is padded to a device multiple by repeating the last
         seed and the padding is sliced off before returning, so results
         are bitwise the single-device vmap results.
+    num_windows: how many leading windows of ``keys``/``events`` are real
+        (``None``: all W).  It is traced data, so plans of one size class
+        share one executable whatever their real length.  The windows past
+        it run no tick: their outputs are zeros (``hot`` False), and
+        ``final`` is the carry after the last real window, as an unpadded
+        plan's is.
 
     Returns (final, outs) with a leading B axis on every leaf: ``outs`` has
     per-window RT series (B, W, C*CHUNK, N, S_ON), window-mean qps/cpu/mem,
@@ -944,9 +965,11 @@ def batched_rollout(state: ClusterState, profiles, t0, keys, events,
             if batched_state:
                 state = jax.tree_util.tree_map(lambda x: x[idx], state)
 
+    if num_windows is None:
+        num_windows = keys.shape[1]
     fn = _batched_fn(batched_state, mesh)
     final, outs = fn(state, profiles, fleet, jnp.float32(t0), keys, events,
-                     det, fc, fold0)
+                     det, fc, fold0, np.int32(num_windows))
     if keys.shape[0] != batch:
         final = jax.tree_util.tree_map(lambda x: x[:batch], final)
         outs = jax.tree_util.tree_map(lambda x: x[:batch], outs)

@@ -9,8 +9,9 @@ Three properties carry this layer:
   the stacked batched state) really are consumed, and consuming them does not perturb results (the golden
   digests in test_fleet.py stay bitwise on the same entry points).
 * **Compile-once bucketing** — two different plans in the same
-  power-of-two size class replay through ONE compiled executable, and the
-  bucketing padding is bitwise invisible on the real window prefix.
+  power-of-two size class, of any real window count, replay through ONE
+  compiled executable; the bucketing padding is bitwise invisible on the
+  real window prefix, and the engine runs no tick of it.
 """
 from __future__ import annotations
 
@@ -73,11 +74,13 @@ def test_shard_request_clamps_to_available_devices():
     assert _trees_equal(ref, got)
 
 
-def test_shard_map_parity_two_devices_subprocess():
+@pytest.mark.parametrize("real", [None, 1])
+def test_shard_map_parity_two_devices_subprocess(real):
     """With 2 forced host devices, the sharded engine — including the
-    pad-to-device-multiple path (B=3 on 2 devices) — is bitwise the vmap
-    engine.  Subprocess because XLA_FLAGS must be set before jax loads."""
-    code = textwrap.dedent("""\
+    pad-to-device-multiple path (B=3 on 2 devices), and a real window count
+    below the plan's length — is bitwise the vmap engine.  Subprocess
+    because XLA_FLAGS must be set before jax loads."""
+    code = f"real = {real!r}\n" + textwrap.dedent("""\
         import jax, jax.numpy as jnp
         import numpy as np
         from repro.cluster import state as cstate
@@ -91,9 +94,12 @@ def test_shard_map_parity_two_devices_subprocess():
         keys = jnp.stack([
             cstate.chunk_key_stream(jax.random.PRNGKey(s), 4)[1]
             .reshape(2, 2, -1) for s in (0, 1, 2)])
-        ref = cstate.batched_rollout(state0, profiles, 0.0, keys, events)
+        ref = cstate.batched_rollout(state0, profiles, 0.0, keys, events,
+                                     num_windows=real)
         got = cstate.batched_rollout(state0, profiles, 0.0, keys, events,
-                                     devices=2)
+                                     devices=2, num_windows=real)
+        if real is not None:
+            assert not np.asarray(got[1]["rt"])[:, real:].any()
         leaves = zip(jax.tree_util.tree_leaves(ref),
                      jax.tree_util.tree_leaves(got))
         assert all(np.array_equal(np.asarray(a), np.asarray(b))
@@ -140,9 +146,12 @@ def test_stacked_batched_state_is_donated():
 # ----------------------------------------------------- compile-once bucketing
 
 
-def test_bucketed_plan_prefix_is_bitwise():
+@pytest.mark.parametrize("real", [None, 3])
+def test_bucketed_plan_prefix_is_bitwise(real):
     """bucket=True pads windows (3 -> 4) and events-per-chunk (3 -> 4);
-    the real-window prefix of the replay must be bitwise unchanged."""
+    the real-window prefix of the replay must be bitwise unchanged.  Told
+    the real count, the engine runs no tick of the padded window: it reads
+    zeros, and the final carry is the unbucketed replay's."""
     log = [("place_on", 0.0, 0, 0, 0, 300.0, 0.4),
            ("place_on", 0.0, 1, 0, 1, 250.0, 1.1),
            ("place_on", 0.0, 2, 0, 2, 200.0, 2.0),
@@ -157,43 +166,84 @@ def test_bucketed_plan_prefix_is_bitwise():
     # prefix-stable key stream: the first 3 windows' keys are unchanged
     np.testing.assert_array_equal(np.asarray(keys_b[:, :3]), np.asarray(keys))
     ref = cstate.batched_rollout(state0, profiles, 0.0, keys, events)
-    got = cstate.batched_rollout(state0, profiles, 0.0, keys_b, ev_b)
+    got = cstate.batched_rollout(state0, profiles, 0.0, keys_b, ev_b,
+                                 num_windows=real)
     for k in ("rt", "qps", "cpu_util", "mem_util", "hot"):
         np.testing.assert_array_equal(
             np.asarray(got[1][k])[:, :3], np.asarray(ref[1][k]), err_msg=k)
+    if real is None:
+        return
+    for k in ("rt", "qps", "cpu_util", "mem_util", "hot"):
+        assert not np.asarray(got[1][k])[:, 3:].any(), k
+    ref_leaves = jax.tree_util.tree_leaves(ref[0])
+    got_leaves = jax.tree_util.tree_leaves(got[0])
+    assert len(ref_leaves) == len(got_leaves)
+    for a, b in zip(ref_leaves, got_leaves):
+        np.testing.assert_array_equal(np.asarray(b), np.asarray(a))
 
 
-def test_same_size_class_plans_share_one_executable():
-    """Two different logs in the same power-of-two size class must hit the
-    same compiled executable — the jit cache grows by exactly one entry
-    for the pair."""
-    log_a = [("place_on", 0.0, 0, 0, 0, 300.0, 0.4),
-             ("place_on", 0.0, 1, 0, 1, 250.0, 1.0),
-             ("place_on", 0.0, 2, 0, 2, 220.0, 2.0)]  # 3 events -> class 4
-    log_b = [("place_off", 0.0, n, 0, 2.0, 4.0, 8.0, 1.5, 35)
-             for n in range(4)]                       # 4 events -> class 4
-    # 5-node scenario: a shape no other test compiles, so the cache delta
-    # below is exactly this test's
+_LOG_A = [("place_on", 0.0, 0, 0, 0, 300.0, 0.4),
+          ("place_on", 0.0, 1, 0, 1, 250.0, 1.0),
+          ("place_on", 0.0, 2, 0, 2, 220.0, 2.0)]  # 3 events -> class 4
+_LOG_B = [("place_off", 0.0, n, 0, 2.0, 4.0, 8.0, 1.5, 35)
+          for n in range(4)]                       # 4 events -> class 4
+
+
+# Each case has a node count no other test compiles, so the cache delta is
+# exactly its own: two logs of 3 windows each, replayed in full, and one
+# log as plans of 3 and of 4 real windows, each told its real count.
+@pytest.mark.parametrize("num_nodes, plans", [
+    (5, [(_LOG_A, 3, None), (_LOG_B, 3, None)]),
+    (7, [(_LOG_A, 3, 3), (_LOG_A, 4, 4)]),
+], ids=["two_logs", "two_real_lengths"])
+def test_same_size_class_plans_share_one_executable(num_nodes, plans):
+    """Different plans in the same power-of-two size class, whatever their
+    logs or real window counts, must hit the same compiled executable — the
+    jit cache grows by exactly one entry for the pair."""
     seeds = (0, 1)
-    state0 = cstate.ClusterState.create(5)
+    state0 = cstate.ClusterState.create(num_nodes)
     profiles = _profiles()
     fn = cstate._batched_fn(stacked=False)
     before = fn._cache_size()
     walls = []
-    for log in (log_a, log_b):
-        ev = cstate.extract_plan(log, 0.0, 3, 2, bucket=True)
+    for log, windows, real in plans:
+        ev = cstate.extract_plan(log, 0.0, windows, 2, bucket=True)
+        assert ev["op"].shape == (4, 2, 4)
         keys = jnp.stack([
             cstate.chunk_key_stream(
                 jax.random.PRNGKey(s), ev["op"].shape[0] * 2)[1]
             .reshape(-1, 2, 2) for s in seeds])
         t0 = time.time()
-        _, outs = cstate.batched_rollout(state0, profiles, 0.0, keys, ev)
+        _, outs = cstate.batched_rollout(state0, profiles, 0.0, keys, ev,
+                                         num_windows=real)
         jax.block_until_ready(outs["rt"])
         walls.append(time.time() - t0)
     assert fn._cache_size() == before + 1, (
         "same-size-class plans must not recompile")
     # the second replay skipped tracing+compilation entirely
     assert walls[1] < walls[0]
+
+
+def test_bucketed_replay_numbers_are_the_unbucketed_ones():
+    """``replay_plan_batched`` with the plan bucketed (3 windows in class 4)
+    gives every seed bitwise the numbers of the unbucketed replay, and its
+    engine runs only the 3 real windows."""
+    from repro.cluster.experiment import replay_plan_batched
+
+    plan = {"log": [("place_on", 0.0, 0, 0, 0, 300.0, 0.4),
+                    ("place_on", 0.0, 1, 0, 1, 250.0, 1.1),
+                    ("place_off", 40.0, 1, 0, 2.0, 4.0, 8.0, 1.6, 50),
+                    ("migrate_on", 80.0, 0, 0, 2, 0)],
+            "t_end": 120.0, "num_nodes": 3, "settle_ticks": 10}
+    seeds = (1, 2, 3)
+    flat = replay_plan_batched(plan, sim_seeds=seeds, bucket=False)
+    bucketed = replay_plan_batched(plan, sim_seeds=seeds, bucket=True)
+    assert (flat["num_windows"], flat["padded_windows"],
+            flat["computed_windows"]) == (3, 3, 3)
+    assert (bucketed["num_windows"], bucketed["padded_windows"],
+            bucketed["computed_windows"]) == (3, 4, 3)
+    assert all(np.isfinite(s["p99_rt"]) for s in flat["seeds"])
+    assert bucketed["seeds"] == flat["seeds"]
 
 
 def test_next_pow2():

@@ -20,8 +20,9 @@ INT32_MAX = np.iinfo(np.int32).max
 def _numpy_pooling(rt, cpu, mem, hot, t_end, settle_ticks, num_windows):
     """The host reduction of the pulled series, seed by seed: RT samples
     above 0 on ticks in [30, t_end), np.percentile, window-level
-    utilization spread over the arrival phase, hot windows of the real
-    prefix.  ``n`` is the seed's sample count."""
+    utilization spread over the arrival phase (over the real prefix for
+    a trace too short to have one), hot windows of the real prefix.
+    ``n`` is the seed's sample count."""
     padded_windows, span = rt.shape[1], rt.shape[2]
     tick = (np.arange(padded_windows)[:, None] * span
             + np.arange(span)[None, :])
@@ -29,7 +30,7 @@ def _numpy_pooling(rt, cpu, mem, hot, t_end, settle_ticks, num_windows):
     w_start = np.arange(padded_windows) * span
     wins = (w_start >= 30) & (w_start + span <= t_end - settle_ticks)
     if not wins.any():
-        wins = np.ones(padded_windows, bool)
+        wins = np.arange(padded_windows) < num_windows
     out = []
     for i in range(rt.shape[0]):
         r = rt[i][valid]

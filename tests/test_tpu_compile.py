@@ -79,7 +79,8 @@ def test_replay_engine_compiles_for_v5e(one_chip):
         jax.ShapeDtypeStruct(
             (REPLAY_SEEDS, REPLAY_WINDOWS, REPLAY_CPW, 2), jnp.uint32,
             sharding=one_chip),) + _shapes(
-        (events, det, fc, cstate.init_fold_state(REPLAY_NODES)), one_chip)
+        (events, det, fc, cstate.init_fold_state(REPLAY_NODES)), one_chip) + (
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),)
     compiled = cstate._batched_fn(stacked=False).lower(*args).compile()
     assert compiled.memory_analysis() is not None
     _, outs = compiled.out_info
